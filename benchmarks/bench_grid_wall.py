@@ -15,7 +15,8 @@ machine, same process, warmed:
   tail drain, one process.
 * ``workers`` — same, fanned over a warm ``--workers`` process pool
   (cells are independent; the pool is started before timing and its
-  cold-start cost is recorded separately).
+  cold-start cost is recorded separately).  Skipped on a TPU backend,
+  where the chip belongs to this process (``workers_skipped`` says so).
 
 The artifact (``BENCH_grid_wall.json``) carries the walls, the
 speedups, and the serial run's aggregate-auction dispatch stats
@@ -41,6 +42,8 @@ import platform as _platform
 import sys
 import time
 from typing import Dict, List, Optional, Tuple
+
+import jax
 
 from repro.core import budget as _budget
 from repro.core import scheduler as _sched
@@ -83,7 +86,6 @@ def host_info() -> Dict:
     """Machine fingerprint recorded in the artifact: wall-clock numbers
     (and the ``check_speedup --grid-floor`` gate) are only comparable
     between runs whose host blocks match."""
-    import jax
     import numpy
 
     return {
@@ -202,7 +204,10 @@ def _measure(full: bool = False) -> Dict:
     n_workers = min(2, os.cpu_count() or 1)
     wall_workers = None
     workers_cold_s = None
-    if n_workers > 1:
+    workers_skipped = None
+    if jax.default_backend() == "tpu":
+        workers_skipped = "tpu backend: the chip belongs to one process"
+    elif n_workers > 1:
         t0 = time.perf_counter()
         ex = grid_executor(n_workers)
         try:
@@ -230,6 +235,7 @@ def _measure(full: bool = False) -> Dict:
         "wall_workers_s": wall_workers,
         "workers": n_workers if wall_workers is not None else 1,
         "workers_cold_start_s": workers_cold_s,
+        "workers_skipped": workers_skipped,
         "speedup_serial_vs_legacy": wall_legacy / wall_serial,
         "speedup_workers_vs_legacy": (
             wall_legacy / wall_workers if wall_workers else None),

@@ -11,6 +11,8 @@ import inspect
 import time
 import traceback
 
+from repro.launch.cache import use_compile_cache
+
 from . import (bench_container_delay, bench_cost_ratio,
                bench_cpu_degradation, bench_grid_wall, bench_makespan,
                bench_prov_delay, bench_roofline, bench_sched_throughput,
@@ -41,6 +43,7 @@ def main() -> None:
     if args.only and args.only not in BENCHES:
         raise SystemExit(
             f"unknown benchmark {args.only!r}; choose from {sorted(BENCHES)}")
+    use_compile_cache()
 
     failures = []
     for name, (mod, desc) in BENCHES.items():

@@ -20,7 +20,7 @@ incremental ``app_image`` / ``app_active`` sets, and sharing-scope masks
 from ``tag_members`` — each computed once per distinct app/tag per
 cycle.  Auction rounds write into resident padded ``[B, T, V]`` buffers
 (:class:`_RoundBuffers`) instead of re-allocating pad+stack copies, so
-the vmapped kernel call pays no per-round host rebuild cost.
+the batched kernel call pays no per-round host rebuild cost.
 
 Two drivers consume the auction:
 
@@ -29,7 +29,7 @@ Two drivers consume the auction:
 * :func:`multi_cycle` — many independent simulations' cycles at once
   (used by ``core.jax_engine.BatchSimEngine``): each round stacks every
   active member's proposal into one ``[B, T, V]`` tensor and scores it
-  with a single vmapped kernel call.
+  with a single kernel call whose grid runs over the member dim.
 
 Tuning knobs (see the README "Tuning knobs" table): ``AUCTION_TAIL_PAIRS``
 (=192) drains a member's auction tail through per-task ``select`` once
@@ -425,7 +425,7 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
     Members are independent simulations, so rounds interleave freely; a
     member drops out as soon as it has no unplaced task, no available VM,
     or a round commits nothing.  Rounds fill the resident power-of-two
-    ``(B, T, V)`` buffers (``_RoundBuffers``) so the vmapped kernel
+    ``(B, T, V)`` buffers (``_RoundBuffers``) so the batched kernel
     recompiles per bucket, not per round, and allocates nothing per call;
     on accelerators the staged device copies are donated back to XLA.
 

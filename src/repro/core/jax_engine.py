@@ -20,7 +20,7 @@ that **rendezvous at auction points**:
    the summed queue × pool pair count of every parked member clears
    ``AUCTION_MIN_PAIRS_ROUND``, all parked cycles are auctioned together
    — pair arrays stack into one resident ``[B, T, V]`` buffer scored by
-   a single ``jax.vmap``'d affinity kernel call
+   a single affinity kernel call whose grid runs over the member dim
    (``kernels.affinity.ops.affinity_batch``, ``core.jax_cycles``);
    below the threshold each parked cycle runs the per-task reference
    path instead (bit-exact either way);
@@ -485,6 +485,7 @@ class GridEntry:
 class BatchResult:
     entries: List[GridEntry]
     wall_s: float
+    dispatch: Dict[str, object]   # the engine's ``dispatch_stats()``
 
     @property
     def results(self) -> List[SimResult]:
@@ -582,4 +583,5 @@ def simulate_batch(
         GridEntry(policy=name, workload=wi, seed=s, result=res)
         for (name, wi, s), res in zip(labels, results)
     ]
-    return BatchResult(entries=entries, wall_s=engine.wall_s)
+    return BatchResult(entries=entries, wall_s=engine.wall_s,
+                       dispatch=engine.dispatch_stats())

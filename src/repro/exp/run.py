@@ -14,12 +14,14 @@ mode) — collects one :class:`repro.exp.metrics.CellMetrics` per
 * ``<out>/paper_grid.md`` — a human-readable report (summary table +
   per-cell makespans).
 
-Workload cells are independent simulations, so the grid scales across
-processes: ``--workers N`` fans cell batches out to a spawn-based
-process pool.  Row order and every per-cell metric are identical to a
-serial run; the merged dispatch stats (rounds, batched calls) reflect
-the worker chunking, which re-batches cells for load balance, so they
-can differ from a serial run's batching.  The full ``paper`` grid
+Workload cells are independent simulations, so on the CPU backend the
+grid scales across processes: ``--workers N`` fans cell batches out to a
+spawn-based process pool.  On a TPU the chip belongs to the one process
+that runs the grid, and ``--workers N > 1`` is refused.  Row order and
+every per-cell metric are identical to a serial run; the merged
+dispatch stats (rounds, batched calls) reflect the worker chunking,
+which re-batches cells for load balance, so they can differ from a
+serial run's batching.  The full ``paper`` grid
 (180 workload cells × 5 policies × 3 seeds) is the intended consumer.
 
 ``--check-floors`` turns the run into a gate: non-zero exit when any
@@ -36,10 +38,13 @@ import os
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+
 from .. import ckpt
 from ..core.jax_engine import (BatchSimEngine, GridMember, StreamInterrupted,
                                predistribute_workload)
 from ..core.types import PlatformConfig, clone_workload
+from ..launch.cache import use_compile_cache
 from ..obs import export as obs_export
 from ..obs import monitor as obs_monitor
 from ..obs import report as obs_report
@@ -235,9 +240,10 @@ def run_grid(
 
     ``workers > 1`` fans the cell batches out to a process pool
     (spawn context — safe with an initialized JAX runtime in the
-    parent).  ``executor`` lets callers reuse a warm pool across runs
-    (the grid-wall benchmark does); it must come from
-    ``grid_executor(workers)``.
+    parent).  CPU backend only: on a TPU the chip belongs to one
+    process, so ``workers > 1`` is refused there.  ``executor`` lets
+    callers reuse a warm pool across runs (the grid-wall benchmark
+    does); it must come from ``grid_executor(workers)``.
 
     ``events`` enables structured-event collection (the artifact's
     ``dispatch.events`` block); ``trace_dir`` additionally writes one
@@ -249,6 +255,11 @@ def run_grid(
     ``report_dir`` additionally writes one ``monitor.json`` + HTML
     dashboard per (cell, policy) — see ``repro.obs.monitor``.
     """
+    if workers > 1 and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"workers={workers}: the TPU belongs to the process that runs "
+            f"the grid, and a worker process cannot open it; run with "
+            f"workers=1 (--workers 1)")
     cfg = cfg or PlatformConfig()
     wcells = list(scenario.workload_cells())
     t0 = time.perf_counter()
@@ -687,7 +698,8 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("--workers", type=int, default=1,
                     help="process-pool width for cell batches (cells are "
                          "independent; the full paper grid parallelizes "
-                         "across cores)")
+                         "across cores).  CPU backend only: refused on a "
+                         "TPU, where the chip belongs to one process")
     ap.add_argument("--redistribute", choices=("finish", "round"),
                     default="finish",
                     help="Algorithm-3 mode: per-task-finish (paper "
@@ -732,6 +744,7 @@ def main(argv: Optional[List[str]] = None) -> None:
                          "tools/check_report.py)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     scenario = get_scenario(args.grid)
     if isinstance(scenario, OnlineScenario):
         if args.workers > 1:

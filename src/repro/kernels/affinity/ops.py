@@ -4,23 +4,18 @@ Two entry points share one core:
 
 * :func:`affinity` — one scheduling cycle, ``[T, V]`` pair arrays.
 * :func:`affinity_batch` — a whole grid of independent simulations'
-  cycles, ``[B, T, V]`` (vmapped over the leading dim).  This is what
-  ``core.jax_engine`` drives: one device pass scores every member's
-  auction round.
+  cycles, ``[B, T, V]``.  This is what ``core.jax_engine`` drives: one
+  device pass scores every member's auction round (the Pallas kernel
+  carries the member dim as a grid axis; the oracle is vmapped over it).
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from .kernel import affinity_pallas
 from .ref import AffinityOut, affinity_ref
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def resolve_use_pallas(flag) -> bool:
@@ -39,41 +34,39 @@ def donation_supported() -> bool:
     return jax.default_backend() in ("tpu", "gpu")
 
 
-def _affinity_core(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
-                   vm_mips, vm_bw, vm_price, gs_read: float, gs_write: float,
-                   bp_ms: float, use_pallas: bool) -> AffinityOut:
-    if use_pallas:
-        vm, t, f, c = affinity_pallas(
-            size_mi, out_mb, budget, missing_mb, cont_ms, tier,
-            vm_mips, vm_bw, vm_price, gs_read, gs_write, bp_ms,
-            interpret=_default_interpret())
-        return AffinityOut(vm, t, f, c)
-    return affinity_ref(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
-                        vm_mips, vm_bw, vm_price, gs_read, gs_write, bp_ms)
-
-
-@partial(jax.jit, static_argnames=("gs_read", "gs_write", "bp_ms", "use_pallas"))
-def affinity(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
-             vm_mips, vm_bw, vm_price, gs_read: float, gs_write: float,
-             bp_ms: float, use_pallas: bool = False) -> AffinityOut:
-    return _affinity_core(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
-                          vm_mips, vm_bw, vm_price, gs_read, gs_write, bp_ms,
-                          use_pallas)
-
-
 def _affinity_batch_impl(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
                          vm_mips, vm_bw, vm_price, gs_read: float,
                          gs_write: float, bp_ms: float,
                          use_pallas: bool = False) -> AffinityOut:
+    if use_pallas:
+        return AffinityOut(*affinity_pallas(
+            size_mi, out_mb, budget, missing_mb, cont_ms, tier,
+            vm_mips, vm_bw, vm_price, gs_read, gs_write, bp_ms))
+
     def one(s, o, b, m, c, t, mi, bw, pr):
-        return _affinity_core(s, o, b, m, c, t, mi, bw, pr,
-                              gs_read, gs_write, bp_ms, use_pallas)
+        return affinity_ref(s, o, b, m, c, t, mi, bw, pr,
+                            gs_read, gs_write, bp_ms)
 
     return jax.vmap(one)(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
                          vm_mips, vm_bw, vm_price)
 
 
 _BATCH_STATIC = ("gs_read", "gs_write", "bp_ms", "use_pallas")
+
+
+@partial(jax.jit, static_argnames=_BATCH_STATIC)
+def affinity(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
+             vm_mips, vm_bw, vm_price, gs_read: float, gs_write: float,
+             bp_ms: float, use_pallas: bool = False) -> AffinityOut:
+    """One scheduling cycle: task arrays ``[T]``, pairs ``[T, V]``, VM
+    arrays ``[V]`` (a batch of one)."""
+    args = (size_mi, out_mb, budget, missing_mb, cont_ms, tier,
+            vm_mips, vm_bw, vm_price)
+    out = _affinity_batch_impl(*(a[None] for a in args), gs_read, gs_write,
+                               bp_ms, use_pallas)
+    return AffinityOut(*(o[0] for o in out))
+
+
 _affinity_batch_jit = jax.jit(_affinity_batch_impl,
                               static_argnames=_BATCH_STATIC)
 # On accelerators the round buffers' device transfers are single-use:

@@ -29,15 +29,36 @@ class AffinityOut(NamedTuple):
 CEIL_TOL = 1.0 - 1e-6  # matches core.costs.ceil_ms (see comment there)
 
 
+def billing_periods(pipe, bp_ms):
+    """Exact ``ceil(pipe / bp_ms)`` for integer-valued f32 ``pipe`` < 2**24,
+    as ``core.costs.billed_cost`` counts periods.
+
+    f32 division is not exact everywhere: XLA rewrites division by a
+    constant into multiplication by its rounded reciprocal (3000 / 1000
+    gives 3.0000002), and TPU division is not correctly rounded.  The
+    products ``q * bp_ms`` are exact, so one step each way restores the
+    integer ceiling."""
+    q = jnp.ceil(pipe / bp_ms)
+    q = jnp.where(q * bp_ms < pipe, q + 1.0, q)
+    return jnp.where((q - 1.0) * bp_ms >= pipe, q - 1.0, q)
+
+
 def pair_estimates(size_mi, out_mb, missing_mb, cont_ms, vm_mips, vm_bw,
                    gs_read, gs_write, bp_ms, vm_price):
-    """Vectorized Eqs. (1)-(5) without provisioning: [T,V] pipe_ms, cost."""
-    in_ms = missing_mb * (1.0 / vm_bw[None, :] + 1.0 / gs_read) * MS
-    out_ms = out_mb[:, None] * (1.0 / vm_bw[None, :] + 1.0 / gs_write) * MS
-    rt_ms = size_mi[:, None] / vm_mips[None, :] * MS
-    pipe = (jnp.ceil(in_ms * CEIL_TOL) + jnp.ceil(rt_ms * CEIL_TOL)
-            + jnp.ceil(out_ms * CEIL_TOL) + cont_ms)
-    cost = jnp.ceil(pipe / bp_ms) * vm_price[None, :]
+    """Vectorized Eqs. (1)-(5) without provisioning: [T,V] pipe_ms, cost.
+
+    Task operands are ``[T, 1]`` columns, VM operands ``[1, V]`` rows, pair
+    operands ``[T, V]``; the Pallas kernel evaluates this same function on
+    its tiles, so both paths share one formula."""
+    # One folded constant: XLA folds ``x * MS * CEIL_TOL`` into
+    # ``x * (MS * CEIL_TOL)`` and Mosaic does not, so writing the product
+    # out keeps the kernel and this oracle bit-equal on a TPU.
+    scale = MS * CEIL_TOL
+    pipe = (jnp.ceil(missing_mb * (1.0 / vm_bw + 1.0 / gs_read) * scale)
+            + jnp.ceil(size_mi / vm_mips * scale)
+            + jnp.ceil(out_mb * (1.0 / vm_bw + 1.0 / gs_write) * scale)
+            + cont_ms)
+    cost = billing_periods(pipe, bp_ms) * vm_price
     return pipe, cost
 
 
@@ -45,9 +66,10 @@ def affinity_ref(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
                  vm_mips, vm_bw, vm_price, gs_read, gs_write,
                  bp_ms) -> AffinityOut:
     """All task arrays [T]; pair arrays [T,V]; vm arrays [V]."""
-    pipe, cost = pair_estimates(size_mi, out_mb, missing_mb, cont_ms,
-                                vm_mips, vm_bw, gs_read, gs_write, bp_ms,
-                                vm_price)
+    pipe, cost = pair_estimates(size_mi[:, None], out_mb[:, None],
+                                missing_mb, cont_ms, vm_mips[None, :],
+                                vm_bw[None, :], gs_read, gs_write, bp_ms,
+                                vm_price[None, :])
     feasible = (tier > 0) & (cost <= budget[:, None] + 1e-6)
     t_eff = jnp.where(feasible, tier, 9).astype(jnp.int32)
     best_tier = jnp.min(t_eff, axis=1)

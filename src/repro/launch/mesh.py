@@ -9,7 +9,14 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the step builders place arrays
+    with ``with_sharding_constraint`` (``parallel.ctx``), which refuses
+    the Explicit axes ``make_mesh`` creates by default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -22,7 +29,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1, data: Optional[int] = None) -> Mesh:
@@ -30,7 +37,7 @@ def make_host_mesh(model: int = 1, data: Optional[int] = None) -> Mesh:
     n = jax.device_count()
     model = min(model, n)
     data = data or (n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def mesh_desc(mesh: Mesh) -> dict:

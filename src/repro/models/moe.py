@@ -26,20 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax ≥ 0.6: public API, replication check kwarg named ``check_vma``
-    from jax import shard_map as _jax_shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4.x: experimental location, kwarg is ``check_rep``
-    from jax.experimental.shard_map import shard_map as _jax_shard_map
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-portable shard_map (the repl-check kwarg was renamed)."""
-    kw = {_SHARD_MAP_CHECK_KW: check_vma}
-    return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
 from .common import ModelConfig, ParamSpec, RunConfig, spec
 from .layers import mlp, mlp_specs
 
@@ -174,7 +160,7 @@ def _moe_shard_map(params, x: jnp.ndarray, cfg: ModelConfig, run: RunConfig,
         return y_tok.reshape(Bl, Sl, d)
 
     xspec = P(batch_axes, seq_axis, None)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P("model", None, None), P("model", None, None),
                   P("model", None, None), xspec),

@@ -152,6 +152,36 @@ def test_grid_matches_sequential_reference():
         assert row["budget_met"] == pytest.approx(ref.budget_met_fraction)
 
 
+def test_simulate_batch_reports_the_grid_dispatch():
+    """``simulate_batch`` returns its engine's dispatch counters, and the
+    same cell through ``run_grid`` reports the same ones."""
+    from repro.core.jax_engine import simulate_batch
+    from repro.exp.scenarios import POLICY_BY_NAME
+    from repro.workflows.workload import cell_workload
+
+    cell = next(iter(TINY.workload_cells()))
+    wl = cell_workload(CFG, cell.app, cell.rate, cell.budget_interval,
+                       cell.workload_seed, TINY.n_workflows, TINY.sizes)
+    br = simulate_batch(CFG, [POLICY_BY_NAME[p] for p in TINY.policies], wl,
+                        seed=cell.seed, batched=True)
+    art = exp_run.run_grid(TINY, batched=True)
+    assert br.dispatch["batched_calls"] > 0
+    for k in ("rounds", "batched_calls", "batched_cycles", "serial_cycles"):
+        assert br.dispatch[k] == art["dispatch"][k], k
+
+
+def test_run_grid_workers_refused_on_tpu(monkeypatch):
+    """One process per chip: a worker pool cannot open the TPU the
+    parent holds, so run_grid refuses workers > 1 on a TPU backend."""
+    monkeypatch.setattr(exp_run.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(exp_run, "use_compile_cache", lambda: None)
+    with pytest.raises(RuntimeError, match="belongs to the process"):
+        exp_run.run_grid(TINY, workers=2)
+    with pytest.raises(RuntimeError, match="belongs to the process"):
+        exp_run.main(["--grid", "paper-smoke", "--workers", "2",
+                      "--out", "/nonexistent"])
+
+
 def test_run_grid_workers_matches_serial():
     """--workers fans cell batches across a spawn pool; rows and
     summaries must equal the serial run exactly (cells are independent

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.affinity.ops import affinity
+from repro.kernels.affinity.ops import affinity, affinity_batch
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ssd.ops import ssd
@@ -71,26 +71,73 @@ def test_ssd_chunked_equals_sequential_recurrence():
                                atol=1e-4)
 
 
-@pytest.mark.parametrize("T,V", [(16, 32), (37, 100), (64, 7), (1, 1)])
+def _affinity_args(rng, lead, T, V):
+    """Seeded random affinity inputs with leading dims ``lead``."""
+    return (
+        jnp.asarray(rng.uniform(10, 900, lead + (T,)), jnp.float32),
+        jnp.asarray(rng.uniform(1, 150, lead + (T,)), jnp.float32),
+        jnp.asarray(rng.uniform(5, 500, lead + (T,)), jnp.float32),
+        jnp.asarray(rng.uniform(0, 200, lead + (T, V)), jnp.float32),
+        jnp.asarray(rng.choice([0., 400., 10000.], lead + (T, V)),
+                    jnp.float32),
+        jnp.asarray(rng.choice([0, 1, 2, 3], lead + (T, V)), jnp.int32),
+        jnp.asarray(rng.choice([2., 4., 8., 16.], lead + (V,)), jnp.float32),
+        jnp.full(lead + (V,), 20.0, jnp.float32),
+        jnp.asarray(rng.choice([1., 2., 4., 8.], lead + (V,)), jnp.float32),
+    )
+
+
+def _assert_bit_equal(r, p):
+    for name, a, b in zip(r._fields, r, p):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.array_equal(a.view(np.uint32), b.view(np.uint32)), name
+
+
+# (37, 100), (37, 2) and (40, 2048): T is not a multiple of the task block.
+@pytest.mark.parametrize("T,V", [(16, 32), (37, 100), (64, 7), (1, 1),
+                                 (37, 2), (40, 2048), (300, 2)])
 def test_affinity_kernel_matches_ref(T, V):
     rng = np.random.default_rng(T * 1000 + V)
-    args = (
-        jnp.asarray(rng.uniform(10, 900, T), jnp.float32),
-        jnp.asarray(rng.uniform(1, 150, T), jnp.float32),
-        jnp.asarray(rng.uniform(5, 500, T), jnp.float32),
-        jnp.asarray(rng.uniform(0, 200, (T, V)), jnp.float32),
-        jnp.asarray(rng.choice([0., 400., 10000.], (T, V)), jnp.float32),
-        jnp.asarray(rng.choice([0, 1, 2, 3], (T, V)), jnp.int32),
-        jnp.asarray(rng.choice([2., 4., 8., 16.], V), jnp.float32),
-        jnp.full((V,), 20.0, jnp.float32),
-        jnp.asarray(rng.choice([1., 2., 4., 8.], V), jnp.float32),
-    )
+    args = _affinity_args(rng, (), T, V)
     r = affinity(*args, gs_read=50., gs_write=30., bp_ms=1000.,
                  use_pallas=False)
     p = affinity(*args, gs_read=50., gs_write=30., bp_ms=1000.,
                  use_pallas=True)
-    for name, a, b in zip(r._fields, r, p):
-        assert np.array_equal(np.asarray(a), np.asarray(b)), name
+    _assert_bit_equal(r, p)
+
+
+@pytest.mark.parametrize("B,T,V", [(4, 37, 100), (3, 20, 2)])
+def test_affinity_batch_mixed_members_matches_ref(B, T, V):
+    """A round buffer as ``multi_cycle`` fills it: live members, an inert
+    padding member (``tier = 0`` everywhere) and rows no VM can afford."""
+    rng = np.random.default_rng(B * 100 + T + V)
+    args = list(_affinity_args(rng, (B,), T, V))
+    args[5] = args[5].at[1].set(0)            # member 1 is inert padding
+    args[2] = args[2].at[0, ::3].set(0.0)      # budget 0: all infeasible
+    kw = dict(gs_read=50., gs_write=30., bp_ms=1000.)
+    r = affinity_batch(*args, use_pallas=False, **kw)
+    p = affinity_batch(*args, use_pallas=True, **kw)
+    _assert_bit_equal(r, p)
+    assert (np.asarray(p.best_vm[1]) == -1).all()
+    assert (np.asarray(p.best_tier[0, ::3]) == 9).all()
+    assert (np.asarray(p.best_vm[0, ::3]) == -1).all()
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_affinity_billing_periods_exact(use_pallas):
+    """A pipeline of exactly k billing periods costs k periods, not k + 1:
+    ``pipe / bp`` must not round up past an integer quotient (XLA turns
+    the division by a constant into a multiplication by 0.001f)."""
+    k = jnp.arange(1, 65, dtype=jnp.float32)           # pipe = k·1000 ms
+    T, V = k.shape[0], 1
+    r = affinity(k * 2.0, jnp.zeros(T), jnp.full((T,), 1e6),
+                 jnp.zeros((T, V)), jnp.zeros((T, V)),
+                 jnp.ones((T, V), jnp.int32), jnp.full((V,), 2.0),
+                 jnp.full((V,), 20.0), jnp.full((V,), 3.0),
+                 gs_read=50., gs_write=30., bp_ms=1000.,
+                 use_pallas=use_pallas)
+    assert np.array_equal(np.asarray(r.est_finish), np.asarray(k) * 1000.0)
+    assert np.array_equal(np.asarray(r.est_cost), np.asarray(k) * 3.0)
 
 
 def test_affinity_tier_priority():

@@ -79,7 +79,8 @@ batch["labels"] = batch["tokens"]
 # single-device reference
 p1, o1, met1 = jax.jit(make_train_step(m))(params, opt, batch)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 fn, *_ = build_train_step(m, mesh, donate=False)
 p2, o2, met2 = fn(params, opt, batch)
 d = max(jax.tree.leaves(jax.tree.map(
