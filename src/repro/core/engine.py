@@ -64,9 +64,10 @@ CycleMeta = Tuple[int, int, List[Tuple[DataKey, float]]]
 def _profile_enabled() -> bool:
     """Opt-in per-phase timing (``REPRO_PROFILE=1``).
 
-    Off by default: the counters wrap the per-dispatch hot path with two
-    ``perf_counter`` calls each, which is measurable at paper scale.  Read
-    per ``SimState`` so tests can toggle via monkeypatch.
+    Off by default: each bracket (:class:`phase`) takes two
+    ``perf_counter`` calls and enters a profiler annotation on the
+    per-dispatch hot path, which is measurable at paper scale.  Read per
+    ``SimState`` so tests can toggle via monkeypatch.
     """
     return _os.environ.get("REPRO_PROFILE") == "1"
 
@@ -90,50 +91,92 @@ def _object_state_forced() -> bool:
 STREAM_SNAPSHOT_VERSION = 2
 
 
+# Timed phases of a profile block: name -> the key that counts its
+# brackets.  A phase's seconds go to "<name>_s" and its trace span is
+# "repro.<name>".  ``SimState`` times the member phases; ``BatchSimEngine``
+# and ``core.jax_cycles.multi_cycle`` time the engine phases of a
+# rendezvous round and of each kernel round of its auction.
+MEMBER_PHASES = {
+    "distribute": "distributions",      # Algorithm 1 / MSLBL at arrival
+    "redistribute": "redistributions",  # Algorithm 3, either mode
+    "select": "selects",                # per-task scheduler.select calls
+    "pipeline": "pipelines",            # execution-pipeline math + caches
+}
+ENGINE_PHASES = {
+    "round.members": "round.members_n",    # resuming the member generators
+    "round.serial": "round.serial_n",      # parked cycles run per task
+    "round.apply": "round.apply_n",        # committing auction placements
+    "auction.build": "auction.build_n",    # queue drain + pair arrays
+    "auction.stage": "auction.stage_n",    # round-buffer reset + proposals
+    "auction.dispatch": "auction.dispatch_n",  # kernel call: copy, launch
+    "auction.pull": "auction.pull_n",      # device wait + copy of results
+    "auction.commit": "auction.commit_n",  # serial-dictatorship commits
+    "auction.tail": "auction.tail_n",      # serial drain of small tails
+}
+_PHASE_KEYS = {name: (name + "_s", count, "repro." + name)
+               for name, count in {**MEMBER_PHASES, **ENGINE_PHASES}.items()}
+
+# jax.profiler.TraceAnnotation, imported with the first bracket so that
+# an engine with profiling off never imports JAX for it.
+_SPAN = None
+
+
+def _span_type():
+    global _SPAN
+    from jax.profiler import TraceAnnotation
+    _SPAN = TraceAnnotation
+    return _SPAN
+
+
+def _phase_block(phases: Dict[str, str]) -> Dict[str, float]:
+    prof = {name + "_s": 0.0 for name in phases}
+    prof.update({count: 0.0 for count in phases.values()})
+    return prof
+
+
 def new_profile() -> Dict[str, float]:
-    """Fresh per-phase counter block (seconds + call counts)."""
-    return {
-        "distribute_s": 0.0,      # Algorithm 1 / MSLBL arrival distribution
-        "redistribute_s": 0.0,    # Algorithm 3 redistribution (either mode)
-        "select_s": 0.0,          # per-task scheduler.select calls
-        "pipeline_s": 0.0,        # execution-pipeline math + cache updates
-        "distributions": 0.0,
-        "redistributions": 0.0,       # Algorithm-3 distribute invocations
-        "redistribute_events": 0.0,   # task finishes feeding them (≥ above
-        #                               in round mode: events coalesce)
-        "selects": 0.0,
-        "pipelines": 0.0,             # _start_pipeline timer pairs
-    }
+    """Fresh per-member block: seconds and bracket count of each member
+    phase, and the task finishes feeding Algorithm 3 (more than its
+    brackets in round mode, where they coalesce)."""
+    prof = _phase_block(MEMBER_PHASES)
+    prof["redistribute_events"] = 0.0
+    return prof
 
 
-# Calibrated-once cost of one perf_counter bracket (two calls), the unit
-# the self-measured profile_overhead_s is denominated in.
-_PAIR_COST_S: Optional[float] = None
+def new_engine_profile() -> Dict[str, float]:
+    """Fresh per-engine block: seconds and bracket count of each engine
+    phase."""
+    return _phase_block(ENGINE_PHASES)
 
 
-def _perf_pair_cost_s() -> float:
-    global _PAIR_COST_S
-    if _PAIR_COST_S is None:
-        n = 10000
-        t0 = _time.perf_counter()
-        for _ in range(n):
-            _time.perf_counter()
-            _time.perf_counter()
-        _PAIR_COST_S = (_time.perf_counter() - t0) / n
-    return _PAIR_COST_S
+class phase:
+    """One bracket of phase ``name`` in the profile block ``prof``, open
+    from construction to :meth:`close`: adds its seconds and one count to
+    the block, and spans it as ``repro.<name>`` on the profiler's clock
+    (recorded only while a ``jax.profiler`` trace runs).  Call sites test
+    ``prof is not None`` first, so profiling off costs that test alone::
 
+        ph = phase(prof, "select") if prof is not None else None
+        ...
+        if ph is not None:
+            ph.close()
+    """
 
-def profile_overhead_s(prof: Dict[str, float]) -> float:
-    """Self-measured cost of the profiling counters themselves: every
-    instrumented phase wraps its body in one ``perf_counter`` bracket,
-    so the overhead is (brackets taken) × (calibrated bracket cost).
-    Surfaced as ``dispatch_stats()["profile"]["profile_overhead_s"]`` so
-    consumers can judge whether the counters perturb what they time."""
-    pairs = (prof.get("distributions", 0.0)
-             + prof.get("redistributions", 0.0)
-             + prof.get("selects", 0.0)
-             + prof.get("pipelines", 0.0))
-    return pairs * _perf_pair_cost_s()
+    __slots__ = ("prof", "keys", "span", "t0")
+
+    def __init__(self, prof: Dict[str, float], name: str):
+        self.prof = prof
+        self.keys = _PHASE_KEYS[name]
+        self.span = (_SPAN or _span_type())(self.keys[2])
+        self.span.__enter__()
+        self.t0 = _time.perf_counter()
+
+    def close(self) -> None:
+        dt = _time.perf_counter() - self.t0
+        seconds, count, _ = self.keys
+        self.prof[seconds] += dt
+        self.prof[count] += 1
+        self.span.__exit__(None, None, None)
 
 
 @dataclasses.dataclass(slots=True)
@@ -361,8 +404,10 @@ class SimState:
         :meth:`StreamState.view` segment of an engine-pooled backing)
         sized for this simulation; implies ``soa``.
 
-        ``profile``: True/False/None — per-phase wall-clock counters.
-        None (default) defers to ``REPRO_PROFILE=1``; the kwarg lets
+        ``profile``: True/False/None — per-phase wall-clock counters,
+        each bracket also a ``repro.<phase>`` span on the profiler's
+        clock (:class:`phase`).  None (default) defers to
+        ``REPRO_PROFILE=1``; the kwarg lets
         tests and benchmarks toggle per engine without mutating
         ``os.environ``.
 
@@ -412,9 +457,10 @@ class SimState:
         self.container_warm = 0
         self.container_init = 0
         self.container_cold = 0
-        # Opt-in per-phase wall-clock counters (REPRO_PROFILE=1): how much
-        # of a run the Algorithm 1/3 budget algebra, selection, and the
-        # pipeline math each cost — see BatchSimEngine.dispatch_stats().
+        # Opt-in per-phase wall-clock counters and profiler spans
+        # (REPRO_PROFILE=1): how much of a run the Algorithm 1/3 budget
+        # algebra, selection, and the pipeline math each cost — see
+        # BatchSimEngine.dispatch_stats().
         self.profile: Optional[Dict[str, float]] = (
             new_profile()
             if (profile if profile is not None else _profile_enabled())
@@ -534,18 +580,18 @@ class SimState:
             st.spare = self.predistributed[wid]  # tasks already carry budgets
             dist_mode = 2
         elif self.policy.budget_mode == "mslbl":
-            t0 = _time.perf_counter() if self.profile is not None else 0.0
+            ph = phase(self.profile, "distribute") \
+                if self.profile is not None else None
             distribute_budget_mslbl(self.cfg, wf, wf.budget)
-            if self.profile is not None:
-                self.profile["distribute_s"] += _time.perf_counter() - t0
-                self.profile["distributions"] += 1
+            if ph is not None:
+                ph.close()
             dist_mode = 1
         else:
-            t0 = _time.perf_counter() if self.profile is not None else 0.0
+            ph = phase(self.profile, "distribute") \
+                if self.profile is not None else None
             st.spare = budget_mod.distribute_budget(self.cfg, wf, wf.budget)
-            if self.profile is not None:
-                self.profile["distribute_s"] += _time.perf_counter() - t0
-                self.profile["distributions"] += 1
+            if ph is not None:
+                ph.close()
             dist_mode = 0
         if ev is not None:
             ev.append(obs_events.BUDGET_DISTRIBUTE, self.now, wid,
@@ -634,7 +680,7 @@ class SimState:
             # path (core.budget.RedistState) is bit-exact with the scalar
             # reference, which REPRO_SCALAR_REDIST=1 forces back on.
             prof = self.profile
-            t0 = _time.perf_counter() if prof is not None else 0.0
+            ph = phase(prof, "redistribute") if prof is not None else None
             if budget_mod._ARRAY_REDIST:
                 rd = st.redist
                 if rd is None:
@@ -647,9 +693,8 @@ class SimState:
                     self.cfg, wf, tid, actual, st.spare,
                     st.unscheduled_seq()
                 )
-            if prof is not None:
-                prof["redistribute_s"] += _time.perf_counter() - t0
-                prof["redistributions"] += 1
+            if ph is not None:
+                ph.close()
                 prof["redistribute_events"] += 1
             if ev is not None:
                 ev.append(obs_events.BUDGET_REDISTRIBUTE, self.now, wid,
@@ -730,7 +775,7 @@ class SimState:
                 self.profile["redistribute_events"] += 1
         else:
             prof = self.profile
-            t0 = _time.perf_counter() if prof is not None else 0.0
+            ph = phase(prof, "redistribute") if prof is not None else None
             if budget_mod._ARRAY_REDIST:
                 rd = st.redist
                 if rd is None:
@@ -743,9 +788,8 @@ class SimState:
                     self.cfg, st.wf, -amount, st.spare,
                     st.unscheduled_seq()
                 )
-            if prof is not None:
-                prof["redistribute_s"] += _time.perf_counter() - t0
-                prof["redistributions"] += 1
+            if ph is not None:
+                ph.close()
                 prof["redistribute_events"] += 1
             if ev is not None:
                 ev.append(obs_events.BUDGET_REDISTRIBUTE, self.now,
@@ -884,7 +928,7 @@ class SimState:
 
     def _flush_wf(self, st: Union[_WfState, _WfView]) -> None:
         prof = self.profile
-        t0 = _time.perf_counter() if prof is not None else 0.0
+        ph = phase(prof, "redistribute") if prof is not None else None
         if budget_mod._ARRAY_REDIST:
             rd = st.redist
             if rd is None:
@@ -897,9 +941,8 @@ class SimState:
                 self.cfg, st.wf, st.pending_surplus, st.spare,
                 st.unscheduled_seq()
             )
-        if prof is not None:
-            prof["redistribute_s"] += _time.perf_counter() - t0
-            prof["redistributions"] += 1
+        if ph is not None:
+            ph.close()
         if self.elog is not None:
             self.elog.append(obs_events.BUDGET_REDISTRIBUTE, self.now,
                              st.wf.wid, -1, st.pending_events,
@@ -922,7 +965,8 @@ class SimState:
             if self.policy.budget_mode == "mslbl" and st.spare > 0:
                 budget_eff += st.spare
             inputs = self._inputs_of(wf, task)
-            t0 = _time.perf_counter() if self.profile is not None else 0.0
+            ph = phase(self.profile, "select") \
+                if self.profile is not None else None
             placement = select(
                 self.cfg,
                 self.policy,
@@ -935,9 +979,8 @@ class SimState:
                 table=cost_tables.table_for(self.cfg, wf),
                 pool=self.pool,
             )
-            if self.profile is not None:
-                self.profile["select_s"] += _time.perf_counter() - t0
-                self.profile["selects"] += 1
+            if ph is not None:
+                ph.close()
             ev = self.elog
             if self.policy.budget_mode == "mslbl":
                 # Spare consumed by how much the estimate exceeds the base.
@@ -1035,7 +1078,8 @@ class SimState:
     def _start_pipeline(
         self, wid: int, tid: int, vm: VM, triggered_provision: bool
     ) -> None:
-        tp0 = _time.perf_counter() if self.profile is not None else 0.0
+        ph = phase(self.profile, "pipeline") \
+            if self.profile is not None else None
         st = self.wf_state[wid]
         wf = st.wf
         task = wf.tasks[tid]
@@ -1140,9 +1184,8 @@ class SimState:
                           warmth)
             ev.append(obs_events.TASK_START, self.now, wid, tid, vm.vmid,
                       warmth, x=missing, y=total_mb)
-        if self.profile is not None:
-            self.profile["pipeline_s"] += _time.perf_counter() - tp0
-            self.profile["pipelines"] += 1
+        if ph is not None:
+            ph.close()
 
     # ---- results ---------------------------------------------------------------
     def _fleet_stats(self) -> Tuple[int, float]:

@@ -43,12 +43,13 @@ decide whether a cycle rides this module at all
 from __future__ import annotations
 
 import threading
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ..kernels.affinity import ops as aff_ops
 from ..sim.cloud import VM, VMPool
+from .engine import phase
 from .scheduler import Placement, Policy, select
 from .types import PlatformConfig, Task
 
@@ -416,8 +417,15 @@ class CycleRequest:
         self.stalled = not committed
 
 
+# The kernel-round counters ``multi_cycle`` adds to a ``stats`` sink.
+KERNEL_COUNTERS = ("kernel_calls", "real_pairs", "kernel_pairs",
+                   "staged_bytes")
+
+
 def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
-                use_pallas: object = "auto"
+                use_pallas: object = "auto",
+                stats: Optional[Dict[str, int]] = None,
+                prof: Optional[Dict[str, float]] = None,
                 ) -> List[List[Optional[Placement]]]:
     """Run every request's auction to its fixed point, scoring all active
     members' rounds with ONE batched kernel call per round.
@@ -436,6 +444,14 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
     dispatch overhead dwarfs the scoring they do.
 
     ``use_pallas``: False / True / "auto" (Pallas on TPU, jnp elsewhere).
+
+    ``stats``: optional sink of :data:`KERNEL_COUNTERS`, added to per
+    kernel round: the round itself, its real pairs (unplaced tasks × VMs
+    of each active request), the pairs of the buffers handed to the
+    kernel (the resident bucket, which may be larger than the round) and
+    the bytes of those nine arrays.  ``prof``: optional engine profile
+    block (``engine.new_engine_profile``) that times each round's
+    ``auction.*`` phases.
     """
     pallas = aff_ops.resolve_use_pallas(use_pallas)
     donate = aff_ops.donation_supported()
@@ -445,7 +461,11 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
             if not r.active:
                 continue
             if len(r.unplaced) * int(r.avail.sum()) < AUCTION_TAIL_PAIRS:
+                ph = phase(prof, "auction.tail") if prof is not None \
+                    else None
                 r.finish_serial()
+                if ph is not None:
+                    ph.close()
             else:
                 active.append(r)
         if not active:
@@ -455,20 +475,38 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
         # Batch dim rounds to 1, 2, 4, … (a solo auction stays unpadded);
         # rows beyond the active members keep the inert padding.
         Bp = 1 << max(len(active) - 1, 0).bit_length()
+        ph = phase(prof, "auction.stage") if prof is not None else None
         bufs = _ROUND_BUFFERS.rb.get(Bp, Tp, Vp)
         for b, r in enumerate(active):
             r.propose_into(bufs, b)
+        if ph is not None:
+            ph.close()
+        if stats is not None:
+            stats["kernel_calls"] += 1
+            stats["real_pairs"] += sum(len(r.unplaced) * r.V
+                                       for r in active)
+            stats["kernel_pairs"] += bufs[3].size
+            stats["staged_bytes"] += sum(a.nbytes for a in bufs)
+        ph = phase(prof, "auction.dispatch") if prof is not None else None
         res = aff_ops.affinity_batch(
             *bufs,
             gs_read=cfg.gs_read_mbps, gs_write=cfg.gs_write_mbps,
             bp_ms=float(cfg.billing_period_ms), use_pallas=pallas,
             donate=donate)
+        if ph is not None:
+            ph.close()
+            ph = phase(prof, "auction.pull")
         best = np.asarray(res.best_vm)
         tiers = np.asarray(res.best_tier)
         fins = np.asarray(res.est_finish)
         costs_ = np.asarray(res.est_cost)
+        if ph is not None:
+            ph.close()
+            ph = phase(prof, "auction.commit")
         for b, r in enumerate(active):
             r.commit(best[b], tiers[b], fins[b], costs_[b])
+        if ph is not None:
+            ph.close()
     return [r.placements for r in requests]
 
 

@@ -60,8 +60,9 @@ import numpy as np
 from . import budget as budget_mod
 from ..chaos import ChaosConfig
 from .engine import (STREAM_SNAPSHOT_VERSION, SimState,
-                     _object_state_forced, profile_overhead_s)
-from .jax_cycles import CycleRequest, multi_cycle
+                     _object_state_forced, _profile_enabled,
+                     new_engine_profile, new_profile, phase)
+from .jax_cycles import KERNEL_COUNTERS, CycleRequest, multi_cycle
 from ..obs import events as obs_events
 from ..obs import monitor as obs_monitor
 from ..obs.events import EventLog
@@ -157,8 +158,11 @@ class BatchSimEngine:
         (:meth:`stream_stats`) reduce over the pooled arrays directly.
 
         ``profile`` / ``events``: per-engine toggles (None defers to
-        ``REPRO_PROFILE`` / ``REPRO_TRACE``).  With events on, every
-        member ``SimState`` gets its own log (exported per cell by
+        ``REPRO_PROFILE`` / ``REPRO_TRACE``).  With profiling on, the
+        engine also times the engine phases of every rendezvous round and
+        of every kernel round of its auctions (``engine.ENGINE_PHASES``,
+        spans ``repro.round.*`` / ``repro.auction.*``).  With events on,
+        every member ``SimState`` gets its own log (exported per cell by
         ``repro.exp.run --trace-dir``) and the driver keeps a separate
         :class:`EventLog` of grid-level events — rendezvous rounds and
         batched auction calls, timestamped by round index (driver events
@@ -217,6 +221,13 @@ class BatchSimEngine:
         self.serial_cycles = 0      # parked member-cycles run per-task
         self.round_pairs: List[int] = []          # aggregate pairs / round
         self.batched_member_pairs: List[int] = []  # per-member pairs when batched
+        # Kernel rounds of every auction (jax_cycles.KERNEL_COUNTERS).
+        self.kernel_stats: Dict[str, int] = dict.fromkeys(KERNEL_COUNTERS, 0)
+        # Engine phase block, None unless profiling (as the members').
+        self.profile: Optional[Dict[str, float]] = (
+            new_engine_profile()
+            if (profile if profile is not None else _profile_enabled())
+            else None)
         self.wall_s = 0.0  # whole-grid wall clock of the last run()
 
     def _member_steps(self, st: SimState) -> Iterator[_CyclePoint]:
@@ -269,6 +280,7 @@ class BatchSimEngine:
             for st in self.states:
                 st.seed_arrivals()
         live = [self._member_steps(st) for st in self.states]
+        prof = self.profile
         while live:
             if ckpt_hook is not None and ckpt_hook(self):
                 self.wall_s += _time.time() - t0
@@ -278,12 +290,15 @@ class BatchSimEngine:
             self.rounds += 1
             points: List[_CyclePoint] = []
             parked: List[Iterator[_CyclePoint]] = []
+            ph = phase(prof, "round.members") if prof is not None else None
             for stepper in live:
                 point = next(stepper, None)
                 if point is None:
                     continue  # member ran to completion
                 points.append(point)
                 parked.append(stepper)
+            if ph is not None:
+                ph.close()
             if not points:
                 break
             owners: List[Tuple[SimState, list, list]] = []
@@ -297,6 +312,8 @@ class BatchSimEngine:
                     self.batched_cycles += 1
                     self.batched_member_pairs.append(p)
                     ride_pairs += p
+                    ph = phase(prof, "auction.build") if prof is not None \
+                        else None
                     tasks, metas, tables = st.drain_queue_for_cycle()
                     owners.append((st, metas, idle))
                     requests.append(CycleRequest(
@@ -304,8 +321,12 @@ class BatchSimEngine:
                         tables=tables))
                 else:
                     self.serial_cycles += 1
+                    ph = phase(prof, "round.serial") if prof is not None \
+                        else None
                     st.sequential_cycle(idle)
                     st.post_cycle()
+                if ph is not None:
+                    ph.close()
             if self.elog is not None:
                 self.elog.append(obs_events.GRID_ROUND, self.rounds,
                                  self.rounds, len(points), len(requests),
@@ -317,11 +338,16 @@ class BatchSimEngine:
                                      self.rounds, len(requests),
                                      d=ride_pairs)
                 all_placements = multi_cycle(self.cfg, requests,
-                                             use_pallas=self.use_pallas)
+                                             use_pallas=self.use_pallas,
+                                             stats=self.kernel_stats,
+                                             prof=prof)
+                ph = phase(prof, "round.apply") if prof is not None else None
                 for (st, metas, idle), placements in zip(owners,
                                                          all_placements):
                     st.apply_cycle_placements(metas, placements, idle)
                     st.post_cycle()
+                if ph is not None:
+                    ph.close()
             live = parked
         # Accumulate (not assign): a resumed stream's wall includes the
         # pre-interrupt segments restored by load_snapshot.
@@ -354,6 +380,8 @@ class BatchSimEngine:
                 "serial_cycles": self.serial_cycles,
                 "round_pairs": self.round_pairs,
                 "batched_member_pairs": self.batched_member_pairs,
+                "kernel_stats": self.kernel_stats,
+                "profile": self.profile,
                 "wall_s": self.wall_s,
                 "elog": self.elog,
             },
@@ -390,6 +418,10 @@ class BatchSimEngine:
         self.serial_cycles = c["serial_cycles"]
         self.round_pairs = list(c["round_pairs"])
         self.batched_member_pairs = list(c["batched_member_pairs"])
+        # Snapshots from before the kernel counters and the engine block
+        # lack them; the engine keeps what its constructor made.
+        self.kernel_stats = dict(c.get("kernel_stats", self.kernel_stats))
+        self.profile = c.get("profile", self.profile)
         self.wall_s = c["wall_s"]
         self.elog = c.get("elog")
         self._resumed = True
@@ -434,6 +466,7 @@ class BatchSimEngine:
                                             default=0),
             "min_member_pairs_batched": min(self.batched_member_pairs,
                                             default=0),
+            **self.kernel_stats,
         }
         # Structured-event counts (repro.obs): member logs + the driver
         # log, summed per kind; {"enabled": False, ...} when tracing is
@@ -444,13 +477,20 @@ class BatchSimEngine:
         # monitors; integer-only so worker-chunk merges are exact.
         out["monitor"] = obs_monitor.monitor_block(
             [st.monitor for st in self.states])
-        # REPRO_PROFILE=1 per-phase counters, summed across members.  The
+        # REPRO_PROFILE=1 per-phase counters: the members' blocks summed,
+        # the engine's block, and the kernel counters again, so that the
+        # block alone says what each timed kernel round carried.  The
         # headline derived number is the Algorithm-3 redistribution share
         # of the grid wall — the quantity behind the ROADMAP's "~45% of a
         # heavy cell" claim and the batched-redistribution decision.
-        profs = [st.profile for st in self.states if st.profile is not None]
-        if profs:
-            agg = {k: float(sum(p[k] for p in profs)) for k in profs[0]}
+        if self.profile is not None:
+            agg = new_profile()
+            for st in self.states:
+                if st.profile is not None:
+                    for k in agg:
+                        agg[k] += st.profile[k]
+            agg.update(self.profile)
+            agg.update(self.kernel_stats)
             # The share's denominator is this engine's own wall; when
             # stats from several (possibly concurrent) engines are merged
             # the consumer must recompute the share from the summed
@@ -458,10 +498,6 @@ class BatchSimEngine:
             agg["engine_wall_s"] = self.wall_s
             agg["redistribute_share_of_wall"] = (
                 agg["redistribute_s"] / self.wall_s if self.wall_s else 0.0)
-            # Self-measured cost of the counters themselves (bracket
-            # count × calibrated perf_counter-pair cost) — merge-safe
-            # (sums across engines like the other absolute seconds).
-            agg["profile_overhead_s"] = profile_overhead_s(agg)
             out["profile"] = agg
         return out
 

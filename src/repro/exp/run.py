@@ -43,6 +43,7 @@ import jax
 from .. import ckpt
 from ..core.jax_engine import (BatchSimEngine, GridMember, StreamInterrupted,
                                predistribute_workload)
+from ..core.jax_cycles import KERNEL_COUNTERS
 from ..core.types import PlatformConfig, clone_workload
 from ..launch.cache import use_compile_cache
 from ..obs import export as obs_export
@@ -81,15 +82,15 @@ def _chunked(seq: Sequence, n: int):
 
 def _merge_stats(parts: List[Dict]) -> Dict:
     """Combine per-engine ``dispatch_stats`` payloads."""
-    out: Dict = {"rounds": 0, "batched_calls": 0, "batched_cycles": 0,
-                 "serial_cycles": 0, "aggregate_pairs_hist": {},
+    summed = ("rounds", "batched_calls", "batched_cycles", "serial_cycles",
+              *KERNEL_COUNTERS)
+    out: Dict = {**dict.fromkeys(summed, 0), "aggregate_pairs_hist": {},
                  "max_member_pairs_batched": 0,
                  "min_member_pairs_batched": 0}
     mins = []
     profiles: List[Dict] = []
     for s in parts:
-        for k in ("rounds", "batched_calls", "batched_cycles",
-                  "serial_cycles"):
+        for k in summed:
             out[k] += s[k]
         for b, n in s["aggregate_pairs_hist"].items():
             out["aggregate_pairs_hist"][b] = \

@@ -23,7 +23,7 @@ import tracemalloc
 import numpy as np
 import pytest
 
-from repro.core.engine import SimEngine, profile_overhead_s
+from repro.core.engine import SimEngine
 from repro.core.jax_engine import BatchSimEngine, StreamInterrupted
 from repro.core.scheduler import EBPSM, MSLBL_MW
 from repro.core.types import PlatformConfig
@@ -292,14 +292,6 @@ def test_dispatch_stats_events_block():
     off.run()
     assert off.dispatch_stats()["events"] == {
         "enabled": False, "total": 0, "by_kind": {}, "dropped": 0}
-
-
-def test_profile_overhead_self_measured():
-    prof = {"distributions": 10.0, "redistributions": 5.0, "selects": 20.0,
-            "pipelines": 15.0}
-    est = profile_overhead_s(prof)
-    assert est > 0.0
-    assert est == pytest.approx(profile_overhead_s(prof))  # deterministic
 
 
 # ---------------------------------------------------------------------------

@@ -410,9 +410,6 @@ def test_profile_counters_opt_in(monkeypatch):
     assert prof["distributions"] == 4    # one Algorithm-1 run per workflow
     assert prof["selects"] > 0
     assert 0.0 <= prof["redistribute_share_of_wall"] <= 1.0
-    # ... and self-reports its own instrumentation cost.
-    assert prof["profile_overhead_s"] >= 0.0
-    assert prof["profile_overhead_s"] < prof["engine_wall_s"] + 1e-9
     assert ref.profile is not None and ref.profile["redistributions"] > 0
     # ... while REPRO_PROFILE=1 stays the ambient default source.
     monkeypatch.setenv("REPRO_PROFILE", "1")
