@@ -419,7 +419,7 @@ class CycleRequest:
 
 # The kernel-round counters ``multi_cycle`` adds to a ``stats`` sink.
 KERNEL_COUNTERS = ("kernel_calls", "real_pairs", "kernel_pairs",
-                   "staged_bytes")
+                   "staged_bytes", "pull_transfers")
 
 
 def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
@@ -449,9 +449,10 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
     kernel round: the round itself, its real pairs (unplaced tasks × VMs
     of each active request), the pairs of the buffers handed to the
     kernel (the resident bucket, which may be larger than the round) and
-    the bytes of those nine arrays.  ``prof``: optional engine profile
-    block (``engine.new_engine_profile``) that times each round's
-    ``auction.*`` phases.
+    the bytes of those nine arrays, and the device-to-host transfers that
+    bring its outputs back (one: the kernel returns them packed).
+    ``prof``: optional engine profile block (``engine.new_engine_profile``)
+    that times each round's ``auction.*`` phases.
     """
     pallas = aff_ops.resolve_use_pallas(use_pallas)
     donate = aff_ops.donation_supported()
@@ -492,14 +493,13 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
             *bufs,
             gs_read=cfg.gs_read_mbps, gs_write=cfg.gs_write_mbps,
             bp_ms=float(cfg.billing_period_ms), use_pallas=pallas,
-            donate=donate)
+            donate=donate, packed=True)
         if ph is not None:
             ph.close()
             ph = phase(prof, "auction.pull")
-        best = np.asarray(res.best_vm)
-        tiers = np.asarray(res.best_tier)
-        fins = np.asarray(res.est_finish)
-        costs_ = np.asarray(res.est_cost)
+        best, tiers, fins, costs_ = aff_ops.unpack_host(np.asarray(res))
+        if stats is not None:
+            stats["pull_transfers"] += 1
         if ph is not None:
             ph.close()
             ph = phase(prof, "auction.commit")

@@ -418,9 +418,11 @@ class BatchSimEngine:
         self.serial_cycles = c["serial_cycles"]
         self.round_pairs = list(c["round_pairs"])
         self.batched_member_pairs = list(c["batched_member_pairs"])
-        # Snapshots from before the kernel counters and the engine block
-        # lack them; the engine keeps what its constructor made.
-        self.kernel_stats = dict(c.get("kernel_stats", self.kernel_stats))
+        # Snapshots from before the kernel counters (or some of them) and
+        # the engine block lack them; the engine keeps what its
+        # constructor made.
+        self.kernel_stats = {**self.kernel_stats,
+                             **c.get("kernel_stats", {})}
         self.profile = c.get("profile", self.profile)
         self.wall_s = c["wall_s"]
         self.elog = c.get("elog")

@@ -7,12 +7,17 @@ Two entry points share one core:
   cycles, ``[B, T, V]``.  This is what ``core.jax_engine`` drives: one
   device pass scores every member's auction round (the Pallas kernel
   carries the member dim as a grid axis; the oracle is vmapped over it).
+  With ``packed=True`` it returns the four outputs as one array
+  (:func:`pack`), which the host pulls in one transfer and reads back
+  with :func:`unpack_host`.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from .kernel import affinity_pallas
 from .ref import AffinityOut, affinity_ref
@@ -34,27 +39,44 @@ def donation_supported() -> bool:
     return jax.default_backend() in ("tpu", "gpu")
 
 
+def pack(out: AffinityOut) -> jax.Array:
+    """The four ``[B, T]`` outputs as one ``int32`` ``[4, B, T]`` array, in
+    field order; the two float fields are carried bit for bit."""
+    return jnp.stack([out.best_vm, out.best_tier,
+                      jax.lax.bitcast_convert_type(out.est_finish, jnp.int32),
+                      jax.lax.bitcast_convert_type(out.est_cost, jnp.int32)])
+
+
+def unpack_host(packed: np.ndarray) -> AffinityOut:
+    """Numpy views of a pulled :func:`pack` array, with the dtypes of
+    :class:`AffinityOut`; nothing is copied."""
+    return AffinityOut(packed[0], packed[1], packed[2].view(np.float32),
+                       packed[3].view(np.float32))
+
+
 def _affinity_batch_impl(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
                          vm_mips, vm_bw, vm_price, gs_read: float,
                          gs_write: float, bp_ms: float,
-                         use_pallas: bool = False) -> AffinityOut:
+                         use_pallas: bool = False, packed: bool = False):
     if use_pallas:
-        return AffinityOut(*affinity_pallas(
+        out = AffinityOut(*affinity_pallas(
             size_mi, out_mb, budget, missing_mb, cont_ms, tier,
             vm_mips, vm_bw, vm_price, gs_read, gs_write, bp_ms))
+    else:
+        def one(s, o, b, m, c, t, mi, bw, pr):
+            return affinity_ref(s, o, b, m, c, t, mi, bw, pr,
+                                gs_read, gs_write, bp_ms)
 
-    def one(s, o, b, m, c, t, mi, bw, pr):
-        return affinity_ref(s, o, b, m, c, t, mi, bw, pr,
-                            gs_read, gs_write, bp_ms)
-
-    return jax.vmap(one)(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
-                         vm_mips, vm_bw, vm_price)
-
-
-_BATCH_STATIC = ("gs_read", "gs_write", "bp_ms", "use_pallas")
+        out = jax.vmap(one)(size_mi, out_mb, budget, missing_mb, cont_ms,
+                            tier, vm_mips, vm_bw, vm_price)
+    return pack(out) if packed else out
 
 
-@partial(jax.jit, static_argnames=_BATCH_STATIC)
+_STATIC = ("gs_read", "gs_write", "bp_ms", "use_pallas")
+_BATCH_STATIC = _STATIC + ("packed",)
+
+
+@partial(jax.jit, static_argnames=_STATIC)
 def affinity(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
              vm_mips, vm_bw, vm_price, gs_read: float, gs_write: float,
              bp_ms: float, use_pallas: bool = False) -> AffinityOut:
@@ -80,7 +102,7 @@ _affinity_batch_donated = jax.jit(_affinity_batch_impl,
 def affinity_batch(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
                    vm_mips, vm_bw, vm_price, gs_read: float, gs_write: float,
                    bp_ms: float, use_pallas: bool = False,
-                   donate: bool = False) -> AffinityOut:
+                   donate: bool = False, packed: bool = False):
     """Batched affinity: every array carries a leading simulation dim ``B``.
 
     Task arrays are ``[B, T]``, pair arrays ``[B, T, V]``, VM arrays
@@ -90,8 +112,12 @@ def affinity_batch(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
     ``donate=True`` routes through the donating jit (see
     :func:`donation_supported`); host-side round buffers stay reusable —
     only the on-device staging copies are consumed.
+
+    Returns an :class:`AffinityOut`, or with ``packed=True`` its
+    :func:`pack`: one ``[4, B, T]`` array, so that the host pulls a round's
+    outputs in one device-to-host transfer instead of four.
     """
     fn = _affinity_batch_donated if donate else _affinity_batch_jit
     return fn(size_mi, out_mb, budget, missing_mb, cont_ms, tier,
               vm_mips, vm_bw, vm_price, gs_read=gs_read, gs_write=gs_write,
-              bp_ms=bp_ms, use_pallas=use_pallas)
+              bp_ms=bp_ms, use_pallas=use_pallas, packed=packed)

@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.affinity.ops import affinity, affinity_batch
+from repro.kernels.affinity.ops import affinity, affinity_batch, unpack_host
+from repro.kernels.affinity.ref import BIG
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.ssd.ops import ssd
@@ -121,6 +122,35 @@ def test_affinity_batch_mixed_members_matches_ref(B, T, V):
     assert (np.asarray(p.best_vm[1]) == -1).all()
     assert (np.asarray(p.best_tier[0, ::3]) == 9).all()
     assert (np.asarray(p.best_vm[0, ::3]) == -1).all()
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_affinity_batch_packed_views_equal_the_outputs(use_pallas):
+    """``packed=True`` returns the four outputs as one ``int32 [4, B, T]``
+    array whose host views are the fields of the unpacked call, float bits
+    included, on a round buffer with an inert member, rows no VM can
+    afford and ordinary rows."""
+    B, T, V = 3, 37, 100
+    rng = np.random.default_rng(7)
+    args = list(_affinity_args(rng, (B,), T, V))
+    args[5] = args[5].at[1].set(0)            # member 1 is inert padding
+    args[2] = args[2].at[0, ::3].set(0.0)      # budget 0: all infeasible
+    kw = dict(gs_read=50., gs_write=30., bp_ms=1000., use_pallas=use_pallas)
+    want = affinity_batch(*args, **kw)
+    packed = affinity_batch(*args, packed=True, **kw)
+    assert packed.shape == (4, B, T) and packed.dtype == jnp.int32
+    got = unpack_host(np.asarray(packed))
+    for name, a, b in zip(want._fields, want, got):
+        a = np.asarray(a)
+        assert (b.dtype, b.shape) == (a.dtype, a.shape), name
+        assert np.array_equal(a.view(np.int32), b.view(np.int32)), name
+    for rows in (got[0][1], got[0][0, ::3]):   # no feasible VM
+        assert (rows == -1).all()
+    for field in got[1:]:
+        want_none = 9 if field.dtype == np.int32 else BIG
+        assert (field[1] == want_none).all()
+        assert (field[0, ::3] == want_none).all()
+    assert (got.best_vm[2] >= 0).any()         # ordinary rows place
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])

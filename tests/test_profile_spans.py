@@ -2,6 +2,7 @@
 times a phase, counts it and spans it on the profiler's clock; the
 engine phases of ``BatchSimEngine`` rounds and ``multi_cycle`` kernel
 rounds; the always-on kernel counters; snapshots."""
+import jax
 import pytest
 
 from repro.core import engine as engine_mod
@@ -164,6 +165,26 @@ def test_kernel_counters_count_what_the_kernel_is_handed(monkeypatch):
     assert stats["kernel_pairs"] == sum(p for p, _ in handed)
     assert stats["staged_bytes"] == sum(b for _, b in handed)
     assert 0 < stats["real_pairs"] <= stats["kernel_pairs"]
+
+
+def test_each_kernel_round_pulls_its_outputs_in_one_transfer(monkeypatch):
+    returned = []
+    orig = ops.affinity_batch
+
+    def recording(*args, **kw):
+        out = orig(*args, **kw)
+        returned.append(out)
+        return out
+
+    monkeypatch.setattr(ops, "affinity_batch", recording)
+    eng = _engine(profile=True)
+    eng.run()
+    stats = eng.dispatch_stats()
+    assert stats["pull_transfers"] == stats["kernel_calls"] \
+        == len(returned) > 0
+    assert stats["profile"]["pull_transfers"] == stats["pull_transfers"]
+    assert all(isinstance(out, jax.Array) and out.shape[0] == 4
+               for out in returned)
 
 
 def test_results_are_identical_with_profile_on_and_off():

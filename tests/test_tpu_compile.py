@@ -9,12 +9,18 @@ tiling, an unaligned block or too much VMEM fails here at no chip time.
 The topology is described inside a fixture, never at import, so that every
 pytest-xdist worker collects the same tests.
 """
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.affinity import ops
 from repro.kernels.affinity.kernel import affinity_pallas
+
+BUCKETS = [(8, 1024, 1024), (8, 2048, 2048), (1, 128, 2048), (1, 2048, 2),
+           (8, 4096, 8), (8, 2048, 128)]
 
 
 @pytest.fixture(scope="module")
@@ -38,17 +44,34 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("B,T,V", [(8, 1024, 1024), (8, 2048, 2048),
-                                   (1, 128, 2048), (1, 2048, 2),
-                                   (8, 4096, 8), (8, 2048, 128)])
-def test_affinity_kernel_compiles_for_v5e(one_chip, B, T, V):
+def _shapes(one_chip, B, T, V):
+    """The nine round-buffer arrays of a ``(B, T, V)`` bucket."""
     f32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                              sharding=one_chip)
-    args = (f32((B, T)), f32((B, T)), f32((B, T)),
+    return (f32((B, T)), f32((B, T)), f32((B, T)),
             f32((B, T, V)), f32((B, T, V)),
             jax.ShapeDtypeStruct((B, T, V), jnp.int32, sharding=one_chip),
             f32((B, V)), f32((B, V)), f32((B, V)))
+
+
+@pytest.mark.parametrize("B,T,V", BUCKETS)
+def test_affinity_kernel_compiles_for_v5e(one_chip, B, T, V):
     fn = jax.jit(lambda *a: affinity_pallas(*a, gs_read=50.0, gs_write=30.0,
                                             bp_ms=1000.0, interpret=False))
-    compiled = fn.lower(*args).compile()
+    compiled = fn.lower(*_shapes(one_chip, B, T, V)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("B,T,V", BUCKETS)
+def test_packed_affinity_batch_compiles_for_v5e(one_chip, monkeypatch,
+                                                B, T, V):
+    """The program ``multi_cycle`` dispatches: the kernel and the packing
+    of its four outputs into one ``int32 [4, B, T]`` array."""
+    monkeypatch.setattr(ops, "affinity_pallas",
+                        partial(affinity_pallas, interpret=False))
+    fn = jax.jit(lambda *a: ops._affinity_batch_impl(
+        *a, 50.0, 30.0, 1000.0, use_pallas=True, packed=True))
+    compiled = fn.lower(*_shapes(one_chip, B, T, V)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    out = compiled.out_info
+    assert (out.shape, out.dtype) == ((4, B, T), jnp.int32)
