@@ -145,8 +145,12 @@ def new_profile() -> Dict[str, float]:
 
 def new_engine_profile() -> Dict[str, float]:
     """Fresh per-engine block: seconds and bracket count of each engine
-    phase."""
-    return _phase_block(ENGINE_PHASES)
+    phase, and the seconds and count of the ``round.serial`` brackets
+    that ran a cycle with no idle VM (a part of ``round.serial_s``)."""
+    prof = _phase_block(ENGINE_PHASES)
+    prof["zero_pair_s"] = 0.0
+    prof["zero_pair_n"] = 0
+    return prof
 
 
 class phase:
@@ -171,12 +175,14 @@ class phase:
         self.span.__enter__()
         self.t0 = _time.perf_counter()
 
-    def close(self) -> None:
+    def close(self) -> float:
+        """Ends the bracket; returns its seconds."""
         dt = _time.perf_counter() - self.t0
         seconds, count, _ = self.keys
         self.prof[seconds] += dt
         self.prof[count] += 1
         self.span.__exit__(None, None, None)
+        return dt
 
 
 @dataclasses.dataclass(slots=True)

@@ -23,7 +23,10 @@ that **rendezvous at auction points**:
    a single affinity kernel call whose grid runs over the member dim
    (``kernels.affinity.ops.affinity_batch``, ``core.jax_cycles``);
    below the threshold each parked cycle runs the per-task reference
-   path instead (bit-exact either way);
+   path instead (bit-exact either way).  ``dispatch_stats()`` records
+   the decision: ``parked_rounds`` / ``parked_pairs`` count the rounds
+   kept off the kernel and their summed pairs, ``ridden_rounds`` /
+   ``ridden_pairs`` those that rode it (one ``batched_calls`` each);
 3. placements commit through the shared ``apply_cycle_placements`` and
    each member resumes toward its next cycle.
 
@@ -82,11 +85,24 @@ AUCTION_MIN_PAIRS_GRID = 2048
 
 # Aggregate-round auction threshold: at each rendezvous the driver sums
 # every parked member's queue × pool pair product and rides one batched
-# ``multi_cycle`` whenever the round total clears this.  Much lower than
-# the per-member threshold — one resident [B, T, V] kernel call amortizes
-# across all parked members, so dozens of small cycles that individually
-# never justified a device call now batch into one.
+# ``multi_cycle`` whenever the round total clears this; below it every
+# parked cycle runs per task.  ``ROUND_COUNTERS`` count the rounds and
+# their pairs on each side.  Much lower than the per-member threshold —
+# one resident [B, T, V] kernel call amortizes across all parked members,
+# so dozens of small cycles that individually never justified a device
+# call batch into one.  The value was set on the CPU.  The chip readings for retuning it
+# are the benchmark's ``serial_us_per_pair`` and ``auction_us_per_pair``
+# in ``grid-montage-lowrate`` (most rounds parked) and ``grid-montage``
+# (most ridden): the average host cost of a pair on each side.  They
+# average over rounds of different sizes; a retune also needs that cost
+# by round size (PERF.md, open questions).
 AUCTION_MIN_PAIRS_ROUND = 1536
+
+# The dispatcher's decision, always counted (``dispatch_stats()``, and the
+# profile block): the rendezvous rounds with pairs kept off the kernel and
+# those that rode it (one ``batched_calls`` each), and their summed pairs.
+ROUND_COUNTERS = ("parked_rounds", "ridden_rounds", "parked_pairs",
+                  "ridden_pairs")
 
 # What a member yields when it parks at a pending scheduling cycle:
 # (state, idle snapshot).  The driver decides serial vs batched.
@@ -223,6 +239,8 @@ class BatchSimEngine:
         self.batched_member_pairs: List[int] = []  # per-member pairs when batched
         # Kernel rounds of every auction (jax_cycles.KERNEL_COUNTERS).
         self.kernel_stats: Dict[str, int] = dict.fromkeys(KERNEL_COUNTERS, 0)
+        # The dispatcher's decisions (ROUND_COUNTERS).
+        self.round_stats: Dict[str, int] = dict.fromkeys(ROUND_COUNTERS, 0)
         # Engine phase block, None unless profiling (as the members').
         self.profile: Optional[Dict[str, float]] = (
             new_engine_profile()
@@ -252,16 +270,32 @@ class BatchSimEngine:
     def _round_rides_kernel(self, points: List[_CyclePoint],
                             pairs: List[int]) -> List[bool]:
         """The dispatcher: which parked cycles of this round are auctioned.
-        Zero-pair cycles (no idle VMs — pure provisioning fallback) never
-        ride: the kernel has nothing to score for them."""
-        self.round_pairs.append(sum(pairs))
+        In ``"auto"`` mode the whole round rides the kernel when its
+        summed pairs reach ``AUCTION_MIN_PAIRS_ROUND``, else every cycle
+        runs per task; ``True`` rides every cycle with pairs, ``"member"``
+        each cycle at ``AUCTION_MIN_PAIRS_GRID`` on its own.  Zero-pair
+        cycles (no idle VMs — pure provisioning fallback) never ride: the
+        kernel has nothing to score for them.
+
+        A round with pairs counts in :attr:`round_stats` as ridden
+        (``ridden_rounds``, its summed pairs to ``ridden_pairs``) when any
+        of its cycles rides, else as parked (``parked_rounds``,
+        ``parked_pairs``)."""
+        total = sum(pairs)
+        self.round_pairs.append(total)
         if self.batched is True:
-            return [p > 0 for p in pairs]
-        if self.batched == "member":
-            return [p >= AUCTION_MIN_PAIRS_GRID for p in pairs]
-        # "auto": one aggregate decision for the whole rendezvous round.
-        ride = sum(pairs) >= AUCTION_MIN_PAIRS_ROUND
-        return [ride and p > 0 for p in pairs]
+            rides = [p > 0 for p in pairs]
+        elif self.batched == "member":
+            rides = [p >= AUCTION_MIN_PAIRS_GRID for p in pairs]
+        else:
+            # "auto": one aggregate decision for the whole rendezvous round.
+            ride = total >= AUCTION_MIN_PAIRS_ROUND
+            rides = [ride and p > 0 for p in pairs]
+        if total:
+            side = "ridden" if any(rides) else "parked"
+            self.round_stats[side + "_rounds"] += 1
+            self.round_stats[side + "_pairs"] += total
+        return rides
 
     def run(
         self,
@@ -326,7 +360,12 @@ class BatchSimEngine:
                     st.sequential_cycle(idle)
                     st.post_cycle()
                 if ph is not None:
-                    ph.close()
+                    dt = ph.close()
+                    if not p:
+                        # No idle VM, so no ride: timed apart, so that the
+                        # rest of round.serial is the parked pairs' cost.
+                        prof["zero_pair_s"] += dt
+                        prof["zero_pair_n"] += 1
             if self.elog is not None:
                 self.elog.append(obs_events.GRID_ROUND, self.rounds,
                                  self.rounds, len(points), len(requests),
@@ -381,6 +420,7 @@ class BatchSimEngine:
                 "round_pairs": self.round_pairs,
                 "batched_member_pairs": self.batched_member_pairs,
                 "kernel_stats": self.kernel_stats,
+                "round_stats": self.round_stats,
                 "profile": self.profile,
                 "wall_s": self.wall_s,
                 "elog": self.elog,
@@ -418,12 +458,15 @@ class BatchSimEngine:
         self.serial_cycles = c["serial_cycles"]
         self.round_pairs = list(c["round_pairs"])
         self.batched_member_pairs = list(c["batched_member_pairs"])
-        # Snapshots from before the kernel counters (or some of them) and
-        # the engine block lack them; the engine keeps what its
-        # constructor made.
+        # Snapshots from before the kernel or round counters (or some of
+        # them) and the engine block, or some of its keys, lack them; the
+        # engine keeps what its constructor made.
         self.kernel_stats = {**self.kernel_stats,
                              **c.get("kernel_stats", {})}
-        self.profile = c.get("profile", self.profile)
+        self.round_stats = {**self.round_stats, **c.get("round_stats", {})}
+        prof = c.get("profile", self.profile)
+        self.profile = prof if prof is None or self.profile is None \
+            else {**self.profile, **prof}
         self.wall_s = c["wall_s"]
         self.elog = c.get("elog")
         self._resumed = True
@@ -451,7 +494,11 @@ class BatchSimEngine:
                 "tasks_remaining": tasks_left, "spare_budget": spare}
 
     def dispatch_stats(self) -> Dict[str, object]:
-        """Aggregate-auction observability for benchmarks and reports."""
+        """The dispatcher's record for benchmarks and reports: rounds,
+        the cycles each side took (``batched_cycles`` / ``serial_cycles``),
+        the summed pairs of the rounds on each side (``ROUND_COUNTERS``),
+        a histogram of each round's summed pairs, and the kernel counters
+        of the auctions that rode."""
         hist: Dict[str, int] = {}
         for p in self.round_pairs:
             b = 1 << max(int(p) - 1, 0).bit_length() if p else 0
@@ -468,6 +515,7 @@ class BatchSimEngine:
                                             default=0),
             "min_member_pairs_batched": min(self.batched_member_pairs,
                                             default=0),
+            **self.round_stats,
             **self.kernel_stats,
         }
         # Structured-event counts (repro.obs): member logs + the driver
@@ -480,11 +528,12 @@ class BatchSimEngine:
         out["monitor"] = obs_monitor.monitor_block(
             [st.monitor for st in self.states])
         # REPRO_PROFILE=1 per-phase counters: the members' blocks summed,
-        # the engine's block, and the kernel counters again, so that the
-        # block alone says what each timed kernel round carried.  The
-        # headline derived number is the Algorithm-3 redistribution share
-        # of the grid wall — the quantity behind the ROADMAP's "~45% of a
-        # heavy cell" claim and the batched-redistribution decision.
+        # the engine's block, and the round and kernel counters again, so
+        # that the block alone says what each timed round and kernel
+        # round carried.  The headline derived number is the Algorithm-3
+        # redistribution share of the grid wall — the quantity behind the
+        # ROADMAP's "~45% of a heavy cell" claim and the
+        # batched-redistribution decision.
         if self.profile is not None:
             agg = new_profile()
             for st in self.states:
@@ -492,6 +541,7 @@ class BatchSimEngine:
                     for k in agg:
                         agg[k] += st.profile[k]
             agg.update(self.profile)
+            agg.update(self.round_stats)
             agg.update(self.kernel_stats)
             # The share's denominator is this engine's own wall; when
             # stats from several (possibly concurrent) engines are merged

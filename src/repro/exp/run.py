@@ -41,8 +41,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 
 from .. import ckpt
-from ..core.jax_engine import (BatchSimEngine, GridMember, StreamInterrupted,
-                               predistribute_workload)
+from ..core.jax_engine import (ROUND_COUNTERS, BatchSimEngine, GridMember,
+                               StreamInterrupted, predistribute_workload)
 from ..core.jax_cycles import KERNEL_COUNTERS
 from ..core.types import PlatformConfig, clone_workload
 from ..launch.cache import use_compile_cache
@@ -83,7 +83,7 @@ def _chunked(seq: Sequence, n: int):
 def _merge_stats(parts: List[Dict]) -> Dict:
     """Combine per-engine ``dispatch_stats`` payloads."""
     summed = ("rounds", "batched_calls", "batched_cycles", "serial_cycles",
-              *KERNEL_COUNTERS)
+              *ROUND_COUNTERS, *KERNEL_COUNTERS)
     out: Dict = {**dict.fromkeys(summed, 0), "aggregate_pairs_hist": {},
                  "max_member_pairs_batched": 0,
                  "min_member_pairs_batched": 0}

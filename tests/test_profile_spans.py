@@ -97,7 +97,7 @@ def test_profile_off_keeps_no_block_and_enters_no_span(spans):
 def test_a_phase_adds_seconds_a_count_and_one_span(spans):
     prof = new_engine_profile()
     assert set(prof) == {n + "_s" for n in ENGINE_PHASES} \
-        | set(ENGINE_PHASES.values())
+        | set(ENGINE_PHASES.values()) | {"zero_pair_s", "zero_pair_n"}
     for _ in range(2):
         phase(prof, "auction.pull").close()
     assert prof["auction.pull_n"] == 2
