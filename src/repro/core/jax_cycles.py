@@ -18,9 +18,12 @@ live-state registry, not from per-VM Python calls: VM-type attributes
 are vmid-indexed gathers, container-delay vectors come from the pool's
 incremental ``app_image`` / ``app_active`` sets, and sharing-scope masks
 from ``tag_members`` — each computed once per distinct app/tag per
-cycle.  Auction rounds write into resident padded ``[B, T, V]`` buffers
-(:class:`_RoundBuffers`) instead of re-allocating pad+stack copies, so
-the batched kernel call pays no per-round host rebuild cost.
+cycle.  An auction's first kernel round writes every member's pair arrays
+into resident padded ``[B, T, V]`` host buffers (:class:`_RoundBuffers`),
+which cross to the device once and stay there; since the scores are
+static, every later round of that auction sends only a small live mask
+(the rows still unplaced and the VMs still free), which the kernel folds
+into the tier.
 
 Two drivers consume the auction:
 
@@ -280,8 +283,9 @@ def set_round_buffer_mesh(mesh) -> None:
     """Install a device mesh for future round-buffer placement.  With
     ``mesh=None`` (the default state) buffers stay host-staged numpy;
     with a mesh, the replicated placement is computed and recorded but
-    — today — only consulted by tests: the actual device_put of the
-    ``[B, T, V]`` stacks is the deferred TPU-tuning work."""
+    — today — only consulted by tests: each auction's ``[B, T, V]``
+    stacks go to the default device, and placing them on the mesh is the
+    deferred TPU-tuning work."""
     global _ROUND_BUFFER_MESH, _ROUND_BUFFER_PLACEMENT
     _ROUND_BUFFER_MESH = mesh
     if mesh is None:
@@ -326,20 +330,28 @@ class CycleRequest:
             and not self.stalled
 
     def propose_into(self, bufs, b: int) -> None:
-        """Write this member's current unplaced rows into batch row ``b``
-        of the shared resident buffers (already reset to inert padding)."""
+        """Write this member's pair arrays, every task row in queue order,
+        into batch row ``b`` of the shared resident buffers (already reset
+        to inert padding), before any commit: every task is unplaced and
+        every VM free."""
         size, out_mb, budget, missing, cont, tier, mips, bw, price = bufs
-        sel = self.unplaced
-        Tr, V = len(sel), self.V
-        size[b, :Tr] = self.size[sel]
-        out_mb[b, :Tr] = self.out_mb[sel]
-        budget[b, :Tr] = self.budget[sel]
-        missing[b, :Tr, :V] = self.missing[sel]
-        cont[b, :Tr, :V] = self.cont[sel]
-        tier[b, :Tr, :V] = self.tier[sel] * self.avail[None, :]
+        T, V = self.T, self.V
+        size[b, :T] = self.size
+        out_mb[b, :T] = self.out_mb
+        budget[b, :T] = self.budget
+        missing[b, :T, :V] = self.missing
+        cont[b, :T, :V] = self.cont
+        tier[b, :T, :V] = self.tier
         mips[b, :V] = self.mips
         bw[b, :V] = self.bw
         price[b, :V] = self.price
+
+    def live_into(self, live, Tp: int) -> None:
+        """Write this member's row of a round's live mask: 1 for each task
+        still unplaced (columns ``[0, Tp)``, by task index) and each VM
+        still free (columns ``[Tp, Tp + V)``)."""
+        live[self.unplaced] = 1
+        live[Tp:Tp + self.V] = self.avail
 
     def _select_serial(self, ti: int) -> Placement:
         """The per-task reference rule for task ``ti`` against the
@@ -368,7 +380,8 @@ class CycleRequest:
         self.unplaced = []
 
     def commit(self, best, tiers, fins, costs_) -> None:
-        """Serial-dictatorship prefix commit: the winner of each VM is its
+        """Serial-dictatorship prefix commit over the kernel's outputs,
+        indexed by task index: the winner of each VM is its
         earliest claimant, and only winners EARLIER than the first loser
         commit this round.  A later round-1 winner could otherwise steal
         the VM an earlier loser takes next — exactly the interleaving
@@ -380,18 +393,18 @@ class CycleRequest:
         this round is deferred (``halted``) and re-auctions against the
         shrunken pool, exactly as the sequential reference would see it."""
         claims: dict = {}
-        for row, ti in enumerate(self.unplaced):
-            j = int(best[row])
+        for ti in self.unplaced:
+            j = int(best[ti])
             if j >= 0 and j not in claims:
                 claims[j] = ti
-        losers = [ti for row, ti in enumerate(self.unplaced)
-                  if int(best[row]) >= 0 and claims[int(best[row])] != ti]
+        losers = [ti for ti in self.unplaced
+                  if int(best[ti]) >= 0 and claims[int(best[ti])] != ti]
         first_loser = min(losers) if losers else None
         next_unplaced = []
         committed = False
         halted = False
-        for row, ti in enumerate(self.unplaced):
-            j = int(best[row])
+        for ti in self.unplaced:
+            j = int(best[ti])
             if halted or (first_loser is not None and ti > first_loser):
                 next_unplaced.append(ti)
                 continue
@@ -407,8 +420,8 @@ class CycleRequest:
                 continue
             if claims[j] == ti:
                 self.placements[ti] = Placement(
-                    self.vms[j], None, int(tiers[row]),
-                    int(fins[row]), float(costs_[row]))
+                    self.vms[j], None, int(tiers[ti]),
+                    int(fins[ti]), float(costs_[ti]))
                 self.avail[j] = False
                 committed = True
             else:
@@ -419,7 +432,8 @@ class CycleRequest:
 
 # The kernel-round counters ``multi_cycle`` adds to a ``stats`` sink.
 KERNEL_COUNTERS = ("kernel_calls", "real_pairs", "kernel_pairs",
-                   "staged_bytes", "pull_transfers")
+                   "staged_bytes", "pull_transfers", "h2d_transfers",
+                   "h2d_bytes")
 
 
 def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
@@ -432,10 +446,16 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
 
     Members are independent simulations, so rounds interleave freely; a
     member drops out as soon as it has no unplaced task, no available VM,
-    or a round commits nothing.  Rounds fill the resident power-of-two
-    ``(B, T, V)`` buffers (``_RoundBuffers``) so the batched kernel
-    recompiles per bucket, not per round, and allocates nothing per call;
-    on accelerators the staged device copies are donated back to XLA.
+    or a round commits nothing.  The first kernel round fills the resident
+    power-of-two ``(B, T, V)`` buffers (``_RoundBuffers``) with every
+    member's whole queue, so the batched kernel recompiles per bucket, not
+    per round, and uploads them (``aff_ops.upload``); with them goes the
+    bucket's resident all-live mask.  Every later round hands the kernel
+    the same device arrays and sends only an ``int32 [B, T + V]`` live
+    mask (``CycleRequest.live_into``): the unplaced rows and free VMs of
+    each member still in the loop, all zero for a member that has left it.
+    The bucket and each member's batch row stay fixed for the call, and
+    the outputs are read by task index.
 
     Requests whose remaining task×VM pair product drops below
     ``AUCTION_TAIL_PAIRS`` leave the fixed point and drain serially
@@ -449,13 +469,16 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
     kernel round: the round itself, its real pairs (unplaced tasks × VMs
     of each active request), the pairs of the buffers handed to the
     kernel (the resident bucket, which may be larger than the round) and
-    the bytes of those nine arrays, and the device-to-host transfers that
-    bring its outputs back (one: the kernel returns them packed).
+    the bytes of those nine arrays, the device-to-host transfers that
+    bring its outputs back (one: the kernel returns them packed), and the
+    host-to-device arrays sent and their bytes (the nine on an auction's
+    first round, the live mask on each later one).
     ``prof``: optional engine profile block (``engine.new_engine_profile``)
     that times each round's ``auction.*`` phases.
     """
     pallas = aff_ops.resolve_use_pallas(use_pallas)
-    donate = aff_ops.donation_supported()
+    dev = None     # the auction's nine device arrays, from its first round
+    row = {}       # request -> its batch row, fixed at the first round
     while True:
         active = []
         for r in requests:
@@ -471,29 +494,43 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
                 active.append(r)
         if not active:
             break
-        Tp = max(_p2(len(r.unplaced)) for r in active)
-        Vp = max(_p2(r.V) for r in active)
-        # Batch dim rounds to 1, 2, 4, … (a solo auction stays unpadded);
-        # rows beyond the active members keep the inert padding.
-        Bp = 1 << max(len(active) - 1, 0).bit_length()
         ph = phase(prof, "auction.stage") if prof is not None else None
-        bufs = _ROUND_BUFFERS.rb.get(Bp, Tp, Vp)
-        for b, r in enumerate(active):
-            r.propose_into(bufs, b)
+        if dev is None:
+            Tp = max(_p2(r.T) for r in active)
+            Vp = max(_p2(r.V) for r in active)
+            # Batch dim rounds to 1, 2, 4, … (a solo auction stays
+            # unpadded); rows beyond the active members keep the inert
+            # padding.
+            Bp = 1 << max(len(active) - 1, 0).bit_length()
+            bufs = _ROUND_BUFFERS.rb.get(Bp, Tp, Vp)
+            for b, r in enumerate(active):
+                r.propose_into(bufs, b)
+                row[r] = b
+            dev = aff_ops.upload(*bufs)
+            Bp, Tp, Vp = bufs[3].shape
+            # Before any commit every row is unplaced and every VM free.
+            live, sent = aff_ops.all_live(Bp, Tp + Vp), bufs
+        else:
+            live = np.zeros((Bp, Tp + Vp), np.int32)
+            for r in active:
+                r.live_into(live[row[r]], Tp)
+            sent = (live,)
         if ph is not None:
             ph.close()
         if stats is not None:
             stats["kernel_calls"] += 1
             stats["real_pairs"] += sum(len(r.unplaced) * r.V
                                        for r in active)
-            stats["kernel_pairs"] += bufs[3].size
-            stats["staged_bytes"] += sum(a.nbytes for a in bufs)
+            stats["kernel_pairs"] += dev[3].size
+            stats["staged_bytes"] += sum(a.nbytes for a in dev)
+            stats["h2d_transfers"] += len(sent)
+            stats["h2d_bytes"] += sum(a.nbytes for a in sent)
         ph = phase(prof, "auction.dispatch") if prof is not None else None
         res = aff_ops.affinity_batch(
-            *bufs,
+            *dev,
             gs_read=cfg.gs_read_mbps, gs_write=cfg.gs_write_mbps,
             bp_ms=float(cfg.billing_period_ms), use_pallas=pallas,
-            donate=donate, packed=True)
+            packed=True, live=live)
         if ph is not None:
             ph.close()
             ph = phase(prof, "auction.pull")
@@ -503,7 +540,8 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
         if ph is not None:
             ph.close()
             ph = phase(prof, "auction.commit")
-        for b, r in enumerate(active):
+        for r in active:
+            b = row[r]
             r.commit(best[b], tiers[b], fins[b], costs_[b])
         if ph is not None:
             ph.close()

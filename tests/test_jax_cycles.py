@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 
 from repro.core.engine import SimEngine
+from repro.core.jax_engine import BatchSimEngine
 from repro.core.scheduler import EBPSM, EBPSM_NS, EBPSM_WS
 from repro.core.types import PlatformConfig
+from repro.kernels.affinity import ops
 from repro.workflows.workload import WorkloadSpec, generate_workload
 
 CFG = PlatformConfig()
@@ -34,6 +36,46 @@ def test_batched_equals_sequential(policy, seed):
     np.testing.assert_allclose([w.cost for w in seq.workflows],
                                [w.cost for w in bat.workflows], rtol=1e-6)
     assert seq.vm_count_by_type == bat.vm_count_by_type
+
+
+@pytest.mark.parametrize("policy", [EBPSM_NS, EBPSM_WS],
+                         ids=lambda p: p.name)
+def test_members_leaving_mid_auction_equal_sequential(policy, monkeypatch):
+    """``multi_cycle`` calls whose members leave the fixed point at
+    different rounds (``policy`` members early, EBPSM members later): the
+    batch keeps its rows, a leaver's mask row is all zero, and every
+    member's placements stay bit-exact."""
+    spec = dict(n_workflows=8, arrival_rate_per_min=30.0, sizes=("medium",),
+                budget_lo=0.4, budget_hi=1.0)
+    # Each member gets a workload of its own: an engine writes budgets
+    # into the tasks it runs.
+    members = lambda: [(p, generate_workload(CFG, WorkloadSpec(seed=s,
+                                                             **spec)))
+                       for s in (2, 3) for p in (EBPSM, policy)]
+    masks = []
+    orig = ops.affinity_batch
+
+    def recording(*args, **kw):
+        # A member's row has real budgets; padding rows hold -1.
+        masks.append((np.asarray(args[2])[:, 0] >= 0, kw["live"]))
+        return orig(*args, **kw)
+
+    monkeypatch.setattr(ops, "affinity_batch", recording)
+    eng = BatchSimEngine(CFG, [(p, wl, 0) for p, wl in members()],
+                         batched=True)
+    results = eng.run()
+    monkeypatch.setattr(ops, "affinity_batch", orig)
+    left = [(member & ~live.any(1)).any() and live.any()
+            for member, live in masks if isinstance(live, np.ndarray)]
+    assert any(left), "no round had one member left and another still in"
+    for (p, wl), res in zip(members(), results):
+        ref = SimEngine(CFG, p, wl, seed=0, batched=False).run()
+        assert [w.finish_ms for w in ref.workflows] == \
+            [w.finish_ms for w in res.workflows]
+        assert [w.cost for w in ref.workflows] == \
+            [w.cost for w in res.workflows]
+        assert ref.vm_count_by_type == res.vm_count_by_type
+        assert ref.vm_seconds_by_type == res.vm_seconds_by_type
 
 
 def test_batched_trace_tiers_match():

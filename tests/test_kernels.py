@@ -154,6 +154,56 @@ def test_affinity_batch_packed_views_equal_the_outputs(use_pallas):
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("B,T,V", [(3, 37, 100), (2, 16, 7)])
+def test_masked_affinity_batch_equals_the_compacted_call(use_pallas, B, T, V):
+    """``live=`` on resident pair arrays equals, on every live row and bit
+    for bit, the call on only the live rows with the dead columns' tier
+    zeroed on the host (what an auction round staged before the mask).
+    Member 0 has left the auction: its mask row is all zero."""
+    rng = np.random.default_rng(B * 1000 + T + V)
+    args = [np.array(a) for a in _affinity_args(rng, (B,), T, V)]
+    args[2][1, ::4] = 0.0                      # rows no VM can afford
+    rows = rng.random((B, T)) < 0.6
+    cols = rng.random((B, V)) < 0.7
+    rows[0] = cols[0] = False
+    live = np.concatenate([rows, cols], axis=1).astype(np.int32)
+    kw = dict(gs_read=50., gs_write=30., bp_ms=1000., use_pallas=use_pallas)
+    got = affinity_batch(*(jnp.asarray(a) for a in args), live=live, **kw)
+    assert (np.asarray(got.best_vm[0]) == -1).all()
+
+    # The compacted call: live rows packed to the front, inert padding after.
+    Tc = max(int(rows.sum(1).max()), 1)
+    pad = [np.zeros((B, Tc), np.float32), np.zeros((B, Tc), np.float32),
+           np.full((B, Tc), -1.0, np.float32),
+           np.zeros((B, Tc, V), np.float32), np.zeros((B, Tc, V), np.float32),
+           np.zeros((B, Tc, V), np.int32)]
+    for b in range(B):
+        sel = np.flatnonzero(rows[b])
+        for dst, src in zip(pad, args[:6]):
+            dst[b, :len(sel)] = src[b, sel]
+        pad[5][b, :len(sel)] *= cols[b][None, :]
+    want = affinity_batch(*pad, *args[6:], **kw)
+    for b in range(B):
+        sel = np.flatnonzero(rows[b])
+        for name, w, g in zip(want._fields, want, got):
+            w = np.asarray(w)[b, :len(sel)]
+            g = np.asarray(g)[b, sel]
+            assert np.array_equal(w.view(np.uint32), g.view(np.uint32)), \
+                (b, name)
+    assert (np.asarray(got.best_vm)[rows] >= 0).any()
+    dead = ~rows
+    assert (np.asarray(got.best_vm)[dead] == -1).all()
+    assert (np.asarray(got.best_tier)[dead] == 9).all()
+
+
+def test_affinity_batch_refuses_a_mask_of_the_wrong_width():
+    args = _affinity_args(np.random.default_rng(1), (2,), 8, 4)
+    with pytest.raises(ValueError, match=r"\[B, T \+ V\]"):
+        affinity_batch(*args, gs_read=50., gs_write=30., bp_ms=1000.,
+                       live=np.ones((2, 8), np.int32))
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
 def test_affinity_billing_periods_exact(use_pallas):
     """A pipeline of exactly k billing periods costs k periods, not k + 1:
     ``pipe / bp`` must not round up past an integer quotient (XLA turns

@@ -3,9 +3,12 @@ times a phase, counts it and spans it on the profiler's clock; the
 engine phases of ``BatchSimEngine`` rounds and ``multi_cycle`` kernel
 rounds; the always-on kernel counters; snapshots."""
 import jax
+import numpy as np
 import pytest
 
 from repro.core import engine as engine_mod
+from repro.core import jax_cycles as jc
+from repro.core import jax_engine
 from repro.core.engine import (ENGINE_PHASES, MEMBER_PHASES,
                                new_engine_profile, new_profile, phase)
 from repro.core.jax_cycles import KERNEL_COUNTERS
@@ -185,6 +188,123 @@ def test_each_kernel_round_pulls_its_outputs_in_one_transfer(monkeypatch):
     assert stats["profile"]["pull_transfers"] == stats["pull_transfers"]
     assert all(isinstance(out, jax.Array) and out.shape[0] == 4
                for out in returned)
+
+
+def test_an_auction_uploads_its_pairs_once_then_sends_a_mask(monkeypatch):
+    """An auction uploads its nine arrays once and hands the same device
+    arrays to every kernel round; its first round goes with the bucket's
+    resident all-live mask, and each later round sends only its live
+    mask."""
+    calls = []          # (auction, positional arrays, keyword arguments, out)
+    auction = [0]
+    orig_cycle, orig = jax_engine.multi_cycle, ops.affinity_batch
+
+    def counting_cycle(*args, **kw):
+        auction[0] += 1
+        return orig_cycle(*args, **kw)
+
+    def recording(*args, **kw):
+        out = orig(*args, **kw)
+        calls.append((auction[0], args, kw, out))
+        return out
+
+    monkeypatch.setattr(jax_engine, "multi_cycle", counting_cycle)
+    monkeypatch.setattr(ops, "affinity_batch", recording)
+    eng = _engine(profile=True)
+    eng.run()
+    stats = eng.dispatch_stats()
+    rounds = {}
+    for a, args, kw, out in calls:
+        rounds.setdefault(a, []).append((args, kw, out))
+    assert stats["kernel_calls"] == len(calls)
+    assert max(len(r) for r in rounds.values()) > 1   # multi-round auctions
+    sent = 0
+    for r in rounds.values():
+        (dev, kw, _), later = r[0], r[1:]
+        assert len(dev) == 9
+        assert all(isinstance(a, jax.Array) for a in dev)
+        B, T, V = dev[3].shape
+        assert kw["live"] is ops.all_live(B, T + V)
+        for args, kw, out in later:
+            assert type(kw["live"]) is np.ndarray
+            assert all(a is b for a, b in zip(args, dev))
+            # Rows placed earlier, and members that left, score as inert.
+            best = ops.unpack_host(np.asarray(out)).best_vm
+            assert (best[kw["live"][:, :T] == 0] == -1).all()
+        # Every round places a task of each member still in, or the member
+        # leaves: the live rows shrink from the first round's real rows
+        # (padding rows hold budget -1).
+        live_rows = [int((np.asarray(dev[2]) >= 0).sum())] + [
+            int(kw["live"][:, :T].sum()) for _, kw, _ in later]
+        assert all(a > b for a, b in zip(live_rows, live_rows[1:]))
+        sent += sum(a.nbytes for a in dev) \
+            + sum(kw["live"].nbytes for _, kw, _ in later)
+    n = len(rounds)
+    assert stats["h2d_transfers"] == 9 * n + stats["kernel_calls"] - n
+    assert stats["h2d_bytes"] == sent
+    assert stats["profile"]["h2d_transfers"] == stats["h2d_transfers"]
+
+
+def _restaging_multi_cycle(cfg, requests, use_pallas="auto", stats=None,
+                           prof=None):
+    """The fixed point as it ran while every round restaged its pairs:
+    each round packs the unplaced rows of the members still in the loop
+    into fresh buffers, with the taken VMs' tier zeroed on the host, and
+    calls the kernel without a mask; its outputs go back to task index."""
+    pallas = ops.resolve_use_pallas(use_pallas)
+    while True:
+        active = []
+        for r in requests:
+            if not r.active:
+                continue
+            if len(r.unplaced) * int(r.avail.sum()) < jc.AUCTION_TAIL_PAIRS:
+                r.finish_serial()
+            else:
+                active.append(r)
+        if not active:
+            break
+        bufs = jc._RoundBuffers._alloc(
+            jc._p2(len(active)), max(jc._p2(len(r.unplaced)) for r in active),
+            max(jc._p2(r.V) for r in active))
+        for b, r in enumerate(active):
+            sel, n, V = r.unplaced, len(r.unplaced), r.V
+            for buf, src in zip(bufs[:3], (r.size, r.out_mb, r.budget)):
+                buf[b, :n] = src[sel]
+            for buf, src in zip(bufs[3:6], (r.missing, r.cont, r.tier)):
+                buf[b, :n, :V] = src[sel]
+            bufs[5][b, :n, :V] *= r.avail[None, :]
+            for buf, src in zip(bufs[6:], (r.mips, r.bw, r.price)):
+                buf[b, :V] = src
+        stats["kernel_calls"] += 1
+        stats["real_pairs"] += sum(len(r.unplaced) * r.V for r in active)
+        out = ops.unpack_host(np.asarray(ops.affinity_batch(
+            *bufs, gs_read=cfg.gs_read_mbps, gs_write=cfg.gs_write_mbps,
+            bp_ms=float(cfg.billing_period_ms), use_pallas=pallas,
+            packed=True)))
+        for b, r in enumerate(active):
+            by_task = []
+            for field in out:
+                full = np.zeros(r.T, field.dtype)
+                full[r.unplaced] = field[b, :len(r.unplaced)]
+                by_task.append(full)
+            r.commit(*by_task)
+    return [r.placements for r in requests]
+
+
+def test_resident_pairs_reach_the_fixed_point_of_restaged_rounds(
+        monkeypatch):
+    """The same kernel rounds, the same real pairs and the same results as
+    rounds that restage the unplaced rows every time."""
+    runs = []
+    for cycle in (jax_engine.multi_cycle, _restaging_multi_cycle):
+        monkeypatch.setattr(jax_engine, "multi_cycle", cycle)
+        eng = _engine()
+        results = eng.run()
+        stats = eng.dispatch_stats()
+        runs.append((_signatures(results), stats["kernel_calls"],
+                     stats["real_pairs"]))
+    assert runs[0] == runs[1]
+    assert runs[0][1] > 0
 
 
 def test_results_are_identical_with_profile_on_and_off():

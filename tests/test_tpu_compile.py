@@ -54,24 +54,43 @@ def _shapes(one_chip, B, T, V):
             f32((B, V)), f32((B, V)), f32((B, V)))
 
 
+def _live(one_chip, B, T, V):
+    """A round's ``int32 [B, T + V]`` live mask."""
+    return jax.ShapeDtypeStruct((B, T + V), jnp.int32, sharding=one_chip)
+
+
 @pytest.mark.parametrize("B,T,V", BUCKETS)
 def test_affinity_kernel_compiles_for_v5e(one_chip, B, T, V):
     fn = jax.jit(lambda *a: affinity_pallas(*a, gs_read=50.0, gs_write=30.0,
                                             bp_ms=1000.0, interpret=False))
-    compiled = fn.lower(*_shapes(one_chip, B, T, V)).compile()
+    compiled = fn.lower(*_shapes(one_chip, B, T, V),
+                        _live(one_chip, B, T, V)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
 @pytest.mark.parametrize("B,T,V", BUCKETS)
 def test_packed_affinity_batch_compiles_for_v5e(one_chip, monkeypatch,
                                                 B, T, V):
-    """The program ``multi_cycle`` dispatches: the kernel and the packing
-    of its four outputs into one ``int32 [4, B, T]`` array."""
+    """The program ``multi_cycle`` dispatches: the kernel, with the live
+    mask split into a row and a column operand, and the packing of its four
+    outputs into one ``int32 [4, B, T]`` array."""
     monkeypatch.setattr(ops, "affinity_pallas",
                         partial(affinity_pallas, interpret=False))
     fn = jax.jit(lambda *a: ops._affinity_batch_impl(
         *a, 50.0, 30.0, 1000.0, use_pallas=True, packed=True))
-    compiled = fn.lower(*_shapes(one_chip, B, T, V)).compile()
+    compiled = fn.lower(*_shapes(one_chip, B, T, V),
+                        _live(one_chip, B, T, V)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     out = compiled.out_info
     assert (out.shape, out.dtype) == ((4, B, T), jnp.int32)
+
+
+@pytest.mark.parametrize("B,T,V", BUCKETS)
+def test_upload_aliases_its_transfers_on_v5e(one_chip, B, T, V):
+    """An auction's upload of its nine arrays compiles to no device work:
+    each output is its donated input's buffer, so nothing is copied."""
+    compiled = ops._upload_donated.lower(*_shapes(one_chip, B, T, V)).compile()
+    text = compiled.as_text()
+    assert text.count("input_output_alias") == 1
+    assert all(f"{{{i}}}: ({i}, {{}}" in text for i in range(9))
+    assert "copy" not in text.split("ENTRY", 1)[1]
