@@ -14,9 +14,8 @@ import traceback
 from repro.launch.cache import use_compile_cache
 
 from . import (bench_container_delay, bench_cost_ratio,
-               bench_cpu_degradation, bench_grid_wall, bench_makespan,
-               bench_prov_delay, bench_roofline, bench_sched_throughput,
-               bench_stream_scale, bench_waas_ml)
+               bench_cpu_degradation, bench_makespan, bench_prov_delay,
+               bench_roofline, bench_sched_throughput, bench_waas_ml)
 from .common import print_rows, write_json
 
 BENCHES = {
@@ -26,9 +25,6 @@ BENCHES = {
     "container_delay": (bench_container_delay, "Fig9 container delay"),
     "cost_ratio": (bench_cost_ratio, "Table3 violated cost/budget"),
     "sched_throughput": (bench_sched_throughput, "Alg2 kernel throughput"),
-    "grid_wall": (bench_grid_wall, "paper-smoke grid end-to-end wall"),
-    "stream_scale": (bench_stream_scale,
-                     "SoA vs object state at open-stream member scale"),
     "waas_ml": (bench_waas_ml, "WaaS->ML bridge platform"),
     "roofline": (bench_roofline, "roofline from dry-run artifacts"),
 }

@@ -17,8 +17,8 @@ Three phases, each checked against the repository's own oracles:
 The grid and stream phases keep ``use_pallas="auto"`` and check every
 policy's per-workflow finish times and costs, and the VM counts, of the
 ``simulate_batch`` run whose counters they print against
-``SimEngine(batched=False)`` — the host-only sequential oracle, which
-never calls the kernel.  Wall and compile seconds and the compile count
+``SimEngine`` — the host-only sequential oracle, which never calls the
+kernel.  Wall and compile seconds and the compile count
 are set-up information, not device metrics.
 
 Exits non-zero, printing no result, when JAX finds no TPU or when any phase
@@ -147,18 +147,18 @@ def _report(name: str, use_pallas, dispatch) -> object:
 
 def parity(name: str, cfg, policies, wl, seed, br, chaos=None) -> int:
     """Each policy's ``simulate_batch`` entry against
-    ``SimEngine(batched=False)``, per workflow; returns the mismatches."""
+    ``SimEngine``, per workflow; returns the mismatches."""
     from repro.core.engine import SimEngine
     from repro.core.types import clone_workload
 
     total = 0
     for pol, entry in zip(policies, br.entries):
         ref = SimEngine(cfg, pol, clone_workload(wl), seed=seed,
-                        batched=False, chaos=chaos).run()
+                        chaos=chaos).run()
         n = _mismatches(ref, entry.result)
         total += n
         print(f"[{name}] parity {pol.name} seed {seed} ({len(wl)} "
-              f"workflows): {n} mismatches vs SimEngine(batched=False)",
+              f"workflows): {n} mismatches vs SimEngine",
               flush=True)
     return total
 

@@ -31,10 +31,10 @@ task's global id and attempt number (the ``degradation_tables``
 pattern), VM lifetimes are keyed by vmid — and vmid allocation order is
 itself deterministic and engine-independent.  The same ``(seed,
 config)`` therefore yields bit-exact event streams across repeat runs,
-across ``SimEngine`` vs ``BatchSimEngine``, across the SoA and object
-state layouts, and through checkpoint/resume (the mutable chaos state —
-attempt counters, wasted-spend tally — rides the snapshot residue;
-the draws are derived state, rebuilt at construction).
+across ``SimEngine`` vs ``BatchSimEngine``, and through checkpoint/resume
+(the mutable chaos state — attempt counters, wasted-spend tally — rides
+the snapshot residue; the draws are derived state, rebuilt at
+construction).
 """
 from __future__ import annotations
 
@@ -150,7 +150,7 @@ class ChaosDraws:
     def vm_lifetime_ms(self, vmid: int) -> int:
         """Exponential spot lifetime for a VM, keyed by vmid (vmids are
         append-only list indices, so the allocation order — and hence
-        every lifetime — is identical across engines and layouts)."""
+        every lifetime — is identical across engines)."""
         rng = np.random.default_rng((*self._life_key, vmid))
         return max(1, int(math.ceil(rng.exponential(self._life_scale))))
 

@@ -33,11 +33,10 @@ Tuning knobs (see the README "Tuning knobs" table):
   pure-Python distribution path on *both* implementations; the cutover
   is bit-invisible.
 
-The round-batched redistribution mode (``redistribute="round"`` on the
-engines) banks per-finish surpluses and flushes them through
-:func:`update_budget_pooled` once per workflow per scheduling cycle —
-semantics-changing (surplus flows coalesce), so it is opt-in and
-A/B-gated rather than bit-parity-gated (see docs/PROFILING.md).
+The engines run Algorithm 3 once per task finish, the paper's trigger.
+:func:`update_budget_pooled` (and its scalar oracle
+:func:`update_budget_pooled_scalar`) redistributes a pooled amount with
+no finishing task: chaos-debt absorption charges wasted spend through it.
 """
 from __future__ import annotations
 
@@ -480,7 +479,7 @@ class RedistState:
     then redistributes across many finishes with the same row set, so
     the caches hit on most calls.
 
-    Lives on the engine's per-workflow ``_WfState`` (never on the
+    Lives on the engine's per-workflow ``_WfView`` (never on the
     :class:`Workflow` itself: structural-sharing clones share task lists
     across grid members, while the mask/budget mirror is per-member
     mutable state).
@@ -625,15 +624,12 @@ def update_budget_pooled(
     surplus: float,
     spare_budget: float,
 ) -> float:
-    """Round-batched Algorithm 3 (array path): one redistribution for a
-    whole rendezvous round's worth of task-finish events.
-
-    ``surplus`` is the banked ``Σ (budget_f − actual_f)`` over the
-    coalesced finishes.  In exact arithmetic the chained per-finish
-    updates and this pooled form conserve the same money; in float they
-    differ (surplus flows reorder), which is why the mode is opt-in and
-    A/B-gated rather than parity-gated.  Bit-exact with
-    :func:`update_budget_pooled_scalar` (the oracle form).
+    """Pooled Algorithm 3 (array path): one redistribution of
+    ``surplus`` with no finishing task — the engines charge a chaos
+    debt (wasted spend) through it as a negative surplus.  Pools the
+    unscheduled sub-budgets, the spare and ``surplus``, clamps at zero
+    and redistributes.  Bit-exact with :func:`update_budget_pooled_scalar`
+    (the oracle form).
     """
     rows = rs.rows()
     if rows.size:

@@ -24,7 +24,7 @@ import math as _math
 import os as _os
 import pickle as _pickle
 import time as _time
-from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -52,11 +52,6 @@ from ..sim.cloud import (VM, VM_BUSY, VM_IDLE, VM_PROVISIONING,
 
 ARRIVAL, FINISH, VM_READY, REAP, REVOKE = 0, 1, 2, 3, 4
 
-# Auction engagement threshold for a solo SimEngine cycle (queue × pool
-# pair count).  The grid engine amortizes device calls across members and
-# uses the lower core.jax_engine.AUCTION_MIN_PAIRS_GRID.
-AUCTION_MIN_PAIRS = 8192
-
 # Queue-order metadata for one cycle's drained tasks: (wid, tid, inputs).
 CycleMeta = Tuple[int, int, List[Tuple[DataKey, float]]]
 
@@ -72,15 +67,6 @@ def _profile_enabled() -> bool:
     return _os.environ.get("REPRO_PROFILE") == "1"
 
 
-def _object_state_forced() -> bool:
-    """``REPRO_OBJECT_STATE=1`` forces the legacy per-workflow object
-    state (`_WfState` dicts/sets) instead of the structure-of-arrays
-    ``StreamState`` default — the debugging/bisection escape hatch, the
-    state-layer analogue of ``REPRO_SCALAR_SELECT`` /
-    ``REPRO_SCALAR_REDIST``.  Read per ``SimState`` so tests can toggle
-    it without re-importing."""
-    return _os.environ.get("REPRO_OBJECT_STATE") == "1"
-
 # Version tag for SimState.snapshot() payloads (bumped on layout
 # changes; repro.ckpt.checkpoint.restore_stream refuses newer ones).
 # v2: chaos residue (attempt/preemption counters, injection tallies) and
@@ -88,6 +74,9 @@ def _object_state_forced() -> bool:
 #     The live monitor (repro.obs.monitor) needs no version of its own:
 #     it rides the opaque elog pickle as ``elog.sub`` — v2 snapshots
 #     written before the monitor existed restore with ``sub = None``.
+#     Older v2 snapshots also carry the two banked-surplus columns of
+#     the retired round-batched Algorithm 3; the loader ignores them, and
+#     refuses a cut that banked any finish (load_snapshot).
 STREAM_SNAPSHOT_VERSION = 2
 
 
@@ -98,7 +87,7 @@ STREAM_SNAPSHOT_VERSION = 2
 # rendezvous round and of each kernel round of its auction.
 MEMBER_PHASES = {
     "distribute": "distributions",      # Algorithm 1 / MSLBL at arrival
-    "redistribute": "redistributions",  # Algorithm 3, either mode
+    "redistribute": "redistributions",  # Algorithm 3 at finish / debt
     "select": "selects",                # per-task scheduler.select calls
     "pipeline": "pipelines",            # execution-pipeline math + caches
 }
@@ -136,11 +125,8 @@ def _phase_block(phases: Dict[str, str]) -> Dict[str, float]:
 
 def new_profile() -> Dict[str, float]:
     """Fresh per-member block: seconds and bracket count of each member
-    phase, and the task finishes feeding Algorithm 3 (more than its
-    brackets in round mode, where they coalesce)."""
-    prof = _phase_block(MEMBER_PHASES)
-    prof["redistribute_events"] = 0.0
-    return prof
+    phase."""
+    return _phase_block(MEMBER_PHASES)
 
 
 def new_engine_profile() -> Dict[str, float]:
@@ -185,67 +171,13 @@ class phase:
         return dt
 
 
-@dataclasses.dataclass(slots=True)
-class _WfState:
-    """Legacy per-workflow object state (``REPRO_OBJECT_STATE=1``).
-
-    Shares the accessor-method interface of :class:`_WfView` so every
-    ``SimState`` transition is state-layout-agnostic; the two layouts
-    are parity-gated in ``tests/test_dispatcher_matrix.py``."""
-
-    wf: Workflow
-    spare: float = 0.0
-    cost: float = 0.0
-    remaining: int = 0
-    finish_ms: int = 0
-    unscheduled: Set[int] = dataclasses.field(default_factory=set)
-    pending_parents: Dict[int, int] = dataclasses.field(default_factory=dict)
-    # Array-path Algorithm 3 (core.budget.RedistState), built lazily at
-    # the first redistribution; None when the scalar oracle is forced.
-    redist: Optional[budget_mod.RedistState] = None
-    # Round-batched mode: surplus banked since the last flush, and the
-    # number of finish events it coalesces.
-    pending_surplus: float = 0.0
-    pending_events: int = 0
-
-    def begin_arrival(self) -> None:
-        wf = self.wf
-        self.remaining = wf.n_tasks
-        self.unscheduled = set(range(wf.n_tasks))
-        self.pending_parents = {t.tid: len(t.parents) for t in wf.tasks}
-
-    def unscheduled_seq(self) -> Sequence[int]:
-        """Unscheduled tids, any order (the scalar Algorithm-3 oracle
-        sorts by rank internally, so ordering is semantics-free)."""
-        return self.unscheduled
-
-    def discard_unscheduled(self, tid: int) -> None:
-        self.unscheduled.discard(tid)
-
-    def add_unscheduled(self, tid: int) -> None:
-        """Chaos re-execution: a revoked/failed task rejoins the pool."""
-        self.unscheduled.add(tid)
-
-    def dec_pending(self, child: int) -> bool:
-        """Decrement the child's pending-parent count; True ⇒ released."""
-        v = self.pending_parents[child] - 1
-        self.pending_parents[child] = v
-        return v == 0
-
-    def make_redist(self, cfg: PlatformConfig) -> budget_mod.RedistState:
-        self.redist = budget_mod.RedistState(cfg, self.wf, self.unscheduled)
-        return self.redist
-
-
 class _WfView:
-    """Per-workflow accessor over the shared :class:`StreamState` arrays
-    (the default state layout).
+    """Per-workflow accessor over the shared :class:`StreamState` arrays.
 
-    Same interface as :class:`_WfState`; the scalar fields are numpy
-    array cells (``float()``/``int()`` narrowing on read keeps every
-    value a Python scalar, so downstream float algebra and JSON output
-    are bit-identical with the object path), and the unscheduled set /
-    pending-parent dict become segment slices of the pooled per-task
+    The scalar fields are numpy array cells (``float()``/``int()``
+    narrowing on read keeps every value a Python scalar, so downstream
+    float algebra and JSON output stay plain), and the unscheduled set
+    and pending-parent counts are segment slices of the pooled per-task
     arrays.  ``redist`` wraps the StreamState Algorithm-3 pool segments
     instead of allocating per-workflow mirrors."""
 
@@ -291,22 +223,6 @@ class _WfView:
     @finish_ms.setter
     def finish_ms(self, v: int) -> None:
         self._ss.finish_ms[self._w] = v
-
-    @property
-    def pending_surplus(self) -> float:
-        return float(self._ss.pending_surplus[self._w])
-
-    @pending_surplus.setter
-    def pending_surplus(self, v: float) -> None:
-        self._ss.pending_surplus[self._w] = v
-
-    @property
-    def pending_events(self) -> int:
-        return int(self._ss.pending_events[self._w])
-
-    @pending_events.setter
-    def pending_events(self, v: int) -> None:
-        self._ss.pending_events[self._w] = v
 
     # -- per-task segments ---------------------------------------------------
     def begin_arrival(self) -> None:
@@ -377,8 +293,6 @@ class SimState:
         seed: int = 0,
         trace: bool = False,
         predistributed: Optional[Dict[int, float]] = None,
-        redistribute: str = "finish",
-        soa: Optional[bool] = None,
         stream: Optional[StreamState] = None,
         profile: Optional[bool] = None,
         events: Union[None, bool, EventLog] = None,
@@ -392,23 +306,12 @@ class SimState:
         engine computes it once per (workload, budget_mode) and shares the
         result across members instead of recomputing per member.
 
-        ``redistribute``: ``"finish"`` (default) runs Algorithm 3 once per
-        task finish — the paper's trigger, bit-exact with the scalar
-        reference; ``"round"`` banks each finish's surplus and runs one
-        pooled redistribution per workflow per scheduling cycle
-        (``flush_redistributions``) — surplus flows coalesce, so results
-        may differ in float; the A/B quality comparison lives in
-        ``benchmarks/bench_grid_wall.py``.
-
-        ``soa``: True/False/None — per-workflow mutable state layout.
-        None (default) resolves to the structure-of-arrays
-        ``StreamState`` unless ``REPRO_OBJECT_STATE=1`` forces the
-        legacy object layout; both are bit-exact (parity-gated in
-        ``tests/test_dispatcher_matrix.py``).
+        Algorithm 3 runs once per task finish, the paper's trigger
+        (:meth:`_handle_finish`), and once per chaos debt.
 
         ``stream``: optional pre-allocated :class:`StreamState` (or a
         :meth:`StreamState.view` segment of an engine-pooled backing)
-        sized for this simulation; implies ``soa``.
+        sized for this simulation; None allocates one.
 
         ``profile``: True/False/None — per-phase wall-clock counters,
         each bracket also a ``repro.<phase>`` span on the profiler's
@@ -437,12 +340,8 @@ class SimState:
         The monitor is reachable from the pickled ``elog`` residue, so
         stream snapshots carry it and resume replays its windows and
         alerts bit-identically."""
-        if redistribute not in ("finish", "round"):
-            raise ValueError(f"redistribute={redistribute!r} "
-                             "(expected 'finish' or 'round')")
         self.cfg = cfg
         self.policy = policy
-        self.redistribute = redistribute
         self.workflows = list(workflows)
         self.predistributed = predistributed
         self.pool = VMPool(cfg)
@@ -451,7 +350,7 @@ class SimState:
         self._seq = 0
         self.now = 0
         self.n_events = 0
-        self.wf_state: Dict[int, Union[_WfState, "_WfView"]] = {}
+        self.wf_state: Dict[int, _WfView] = {}
         self.running: Dict[Tuple[int, int], _Running] = {}
         self.vm_bound: Dict[int, Tuple[int, int]] = {}  # vmid -> (wid, tid)
         self.trace_rows: List[tuple] = [] if trace else None
@@ -514,15 +413,9 @@ class SimState:
         for w in self.workflows:
             self._task_base[w.wid] = base
             base += w.n_tasks
-        # State layout: SoA StreamState (default) vs legacy objects.
-        self.soa = (not _object_state_forced()) if soa is None else bool(soa)
-        if stream is not None:
-            if not self.soa:
-                raise ValueError("stream= requires the SoA state layout")
-            self.stream: Optional[StreamState] = stream
-        else:
-            self.stream = (StreamState(len(self.workflows), total_tasks)
-                           if self.soa else None)
+        self.stream: StreamState = (
+            stream if stream is not None
+            else StreamState(len(self.workflows), total_tasks))
 
     # ---- event plumbing ----------------------------------------------------
     def _push(self, t_ms: int, kind: int, payload: tuple) -> None:
@@ -572,10 +465,7 @@ class SimState:
     # ---- handlers --------------------------------------------------------------
     def _handle_arrival(self, wid: int) -> None:
         wf = self.workflows[wid]
-        if self.soa:
-            st = _WfView(wf, self.stream, wid, self._task_base[wid])
-        else:
-            st = _WfState(wf=wf)
+        st = _WfView(wf, self.stream, wid, self._task_base[wid])
         st.begin_arrival()
         self.wf_state[wid] = st
         ev = self.elog
@@ -670,17 +560,6 @@ class SimState:
             if ev is not None:
                 ev.append(obs_events.BUDGET_SPARE, self.now, wid, tid,
                           x=task.budget - actual, y=st.spare)
-        elif self.redistribute == "round":
-            # Round-batched Algorithm 3: bank the surplus; the pooled
-            # redistribution runs once per workflow per scheduling cycle
-            # (flush_redistributions), coalescing every finish in between.
-            st.pending_surplus += task.budget - actual
-            st.pending_events += 1
-            if self.profile is not None:
-                self.profile["redistribute_events"] += 1
-            if ev is not None:
-                ev.append(obs_events.BUDGET_SPARE, self.now, wid, tid,
-                          x=task.budget - actual, y=st.pending_surplus)
         else:
             # Algorithm 3: one redistribution per task finish.  The array
             # path (core.budget.RedistState) is bit-exact with the scalar
@@ -701,7 +580,6 @@ class SimState:
                 )
             if ph is not None:
                 ph.close()
-                prof["redistribute_events"] += 1
             if ev is not None:
                 ev.append(obs_events.BUDGET_REDISTRIBUTE, self.now, wid,
                           tid, 1, x=task.budget - actual, y=st.spare)
@@ -719,7 +597,7 @@ class SimState:
         return run.actual_cost  # computed at dispatch time
 
     # ---- chaos transitions (repro.chaos) ---------------------------------------
-    def _fail_attempt(self, run: _Running, st: Union["_WfState", "_WfView"],
+    def _fail_attempt(self, run: _Running, st: _WfView,
                       wid: int, tid: int, attempt: int) -> None:
         """An execution attempt failed: the VM worked (and bills) in full
         but produced no output — no cache_put, no child release; the task
@@ -738,7 +616,7 @@ class SimState:
             ev.append(obs_events.VM_IDLE, self.now, vm.vmid)
         self._requeue_task(st, wid, tid, actual)
 
-    def _requeue_task(self, st: Union["_WfState", "_WfView"], wid: int,
+    def _requeue_task(self, st: _WfView, wid: int,
                       tid: int, wasted: float) -> None:
         """Put a killed/failed task back on the ready queue (its parents
         all finished, so it is ready by construction).  The wasted spend
@@ -758,11 +636,10 @@ class SimState:
                              self.task_attempts.get(key, 0),
                              self.task_preempts.get(key, 0))
 
-    def _absorb_chaos_debt(self, st: Union["_WfState", "_WfView"],
+    def _absorb_chaos_debt(self, st: _WfView,
                            amount: float) -> None:
         """Charge wasted spend to the budget layer: MSLBL pays from its
-        spare pot; round-batched banking nets it against pending surplus;
-        per-finish Algorithm 3 runs a pooled redistribution with the
+        spare pot; Algorithm 3 runs a pooled redistribution with the
         debt as negative surplus (spare + unscheduled sub-budgets absorb
         it, clamped at zero — overruns show up as budget violations,
         exactly like benign cost overruns)."""
@@ -774,11 +651,6 @@ class SimState:
             if ev is not None:
                 ev.append(obs_events.BUDGET_SPARE, self.now, st.wf.wid, -1,
                           x=-amount, y=st.spare)
-        elif self.redistribute == "round":
-            st.pending_surplus -= amount
-            st.pending_events += 1
-            if self.profile is not None:
-                self.profile["redistribute_events"] += 1
         else:
             prof = self.profile
             ph = phase(prof, "redistribute") if prof is not None else None
@@ -796,7 +668,6 @@ class SimState:
                 )
             if ph is not None:
                 ph.close()
-                prof["redistribute_events"] += 1
             if ev is not None:
                 ev.append(obs_events.BUDGET_REDISTRIBUTE, self.now,
                           st.wf.wid, -2, 1, x=-amount, y=st.spare)
@@ -919,48 +790,10 @@ class SimState:
             if ev is not None:
                 ev.append(obs_events.VM_REAP, self.now, vm.vmid)
 
-    # ---- round-batched Algorithm 3 (redistribute="round") --------------------
-    def flush_redistributions(self) -> None:
-        """Run the banked pooled redistribution of every workflow with a
-        task in the current ready queue — their sub-budgets are about to
-        be read by selection.  Workflows with banked surplus but nothing
-        queued keep coalescing until they queue again (or finalize)."""
-        if self.redistribute != "round" or not self.queue:
-            return
-        for wid in sorted({e[1] for e in self.queue}):
-            st = self.wf_state[wid]
-            if st.pending_events:
-                self._flush_wf(st)
-
-    def _flush_wf(self, st: Union[_WfState, _WfView]) -> None:
-        prof = self.profile
-        ph = phase(prof, "redistribute") if prof is not None else None
-        if budget_mod._ARRAY_REDIST:
-            rd = st.redist
-            if rd is None:
-                rd = st.make_redist(self.cfg)
-            st.spare = budget_mod.update_budget_pooled(
-                self.cfg, st.wf, rd, st.pending_surplus, st.spare
-            )
-        else:
-            st.spare = budget_mod.update_budget_pooled_scalar(
-                self.cfg, st.wf, st.pending_surplus, st.spare,
-                st.unscheduled_seq()
-            )
-        if ph is not None:
-            ph.close()
-        if self.elog is not None:
-            self.elog.append(obs_events.BUDGET_REDISTRIBUTE, self.now,
-                             st.wf.wid, -1, st.pending_events,
-                             x=st.pending_surplus, y=st.spare)
-        st.pending_surplus = 0.0
-        st.pending_events = 0
-
     # ---- scheduling cycles (Alg. 2) ------------------------------------------
     def sequential_cycle(self, idle: Optional[List[VM]] = None) -> None:
         """Per-task reference cycle: drain the ready queue in order, calling
         ``scheduler.select`` against the live idle pool for each task."""
-        self.flush_redistributions()
         idle = self.pool.idle_vms() if idle is None else idle
         while self.queue:
             est, wid, tid = heapq.heappop(self.queue)
@@ -1022,7 +855,6 @@ class SimState:
         (task, app, owner_tag, inputs) rows the auction scores, the
         (wid, tid, inputs) metadata the commit step needs, and the
         per-task cost tables the auction's serial resolution reads."""
-        self.flush_redistributions()
         ordered = []
         while self.queue:
             ordered.append(heapq.heappop(self.queue))
@@ -1207,13 +1039,6 @@ class SimState:
              for vm in self.pool.vms))
 
     def finalize(self, wall_s: float = 0.0) -> SimResult:
-        if self.redistribute == "round":
-            # Flush any still-banked surplus so spare/budget invariants
-            # hold post-run (results don't read budgets, but tests and
-            # conservation checks do).
-            for st in self.wf_state.values():
-                if st.pending_events:
-                    self._flush_wf(st)
         if self.elog is not None:
             # Close the remaining leases in the event stream before the
             # pool stamps their termination — the event-derived fleet
@@ -1268,10 +1093,8 @@ class SimState:
     def snapshot(self) -> Dict[str, object]:
         """Serializable snapshot: ``{"arrays", "residue", "version"}``.
 
-        ``arrays`` is the StreamState persisted block (gathered from the
-        object layout when ``soa=False`` — the interchange format is
-        layout-independent, so a snapshot written by either layout
-        restores into either) plus the per-task mutable ``Task`` fields
+        ``arrays`` is the StreamState persisted block plus the per-task
+        mutable ``Task`` fields
         Algorithm 1/3 writes (budget/level/rank), in global-id order;
         an ``order`` array preserves ``wf_state`` insertion order
         (finalize and metric grouping iterate it).  ``residue`` is one
@@ -1281,29 +1104,7 @@ class SimState:
         and the resource-sharing counters.  Derived state — Algorithm-3
         pools, cost tables, rank/input caches — is rebuilt lazily and
         bit-identically after :meth:`load_snapshot`."""
-        n_wf = len(self.workflows)
-        total_tasks = sum(w.n_tasks for w in self.workflows)
-        if self.soa:
-            arrays = self.stream.snapshot_arrays()
-        else:
-            arrays = {name: np.zeros(n_wf if per_wf else total_tasks,
-                                     dtype=dt)
-                      for per_wf, fields in
-                      ((True, StreamState.WF_FIELDS),
-                       (False, StreamState.TASK_FIELDS))
-                      for name, dt in fields}
-            for wid, st in self.wf_state.items():
-                arrays["arrived"][wid] = True
-                for name in ("spare", "cost", "pending_surplus",
-                             "remaining", "finish_ms", "pending_events"):
-                    arrays[name][wid] = getattr(st, name)
-                t0 = self._task_base[wid]
-                pp = arrays["pending_parents"]
-                for tid, v in st.pending_parents.items():
-                    pp[t0 + tid] = v
-                un = arrays["unscheduled"]
-                for tid in st.unscheduled:
-                    un[t0 + tid] = True
+        arrays = self.stream.snapshot_arrays()
         arrays["order"] = np.fromiter(self.wf_state, np.int64,
                                       count=len(self.wf_state))
         arrays["task_budget"] = np.array(
@@ -1344,13 +1145,18 @@ class SimState:
 
     def load_snapshot(self, snap: Dict[str, object]) -> None:
         """Restore a :meth:`snapshot` into this freshly-constructed state
-        (same cfg/policy/workloads/seed/redistribute — the caller
-        rebuilds those deterministically; only mutable state loads)."""
+        (same cfg/policy/workloads/seed — the caller rebuilds those
+        deterministically; only mutable state loads)."""
         if snap.get("version", 1) > STREAM_SNAPSHOT_VERSION:
             raise ValueError(
                 f"snapshot version {snap.get('version')} is newer than "
                 f"supported {STREAM_SNAPSHOT_VERSION}")
         arrays: Dict[str, np.ndarray] = snap["arrays"]
+        if "pending_events" in arrays and arrays["pending_events"].any():
+            raise ValueError(
+                "snapshot holds banked round-mode Algorithm-3 surplus "
+                "(pending_events > 0); that mode is gone and the cut "
+                "cannot continue under per-finish redistribution")
         residue = _pickle.loads(snap["residue"])
         # Mutable per-task fields written by Algorithm 1/3 / MSLBL.
         tb = arrays["task_budget"].tolist()
@@ -1366,30 +1172,11 @@ class SimState:
                 i += 1
         # Per-workflow state, in the checkpointed insertion order.
         order = arrays["order"].tolist()
-        self.wf_state = {}
-        if self.soa:
-            self.stream.load_arrays(arrays)
-            for wid in order:
-                self.wf_state[wid] = _WfView(
-                    self.workflows[wid], self.stream, wid,
-                    self._task_base[wid])
-        else:
-            for wid in order:
-                wf = self.workflows[wid]
-                t0 = self._task_base[wid]
-                n = wf.n_tasks
-                st = _WfState(wf=wf)
-                st.spare = float(arrays["spare"][wid])
-                st.cost = float(arrays["cost"][wid])
-                st.pending_surplus = float(arrays["pending_surplus"][wid])
-                st.remaining = int(arrays["remaining"][wid])
-                st.finish_ms = int(arrays["finish_ms"][wid])
-                st.pending_events = int(arrays["pending_events"][wid])
-                st.unscheduled = set(np.flatnonzero(
-                    arrays["unscheduled"][t0:t0 + n]).tolist())
-                st.pending_parents = dict(enumerate(
-                    arrays["pending_parents"][t0:t0 + n].tolist()))
-                self.wf_state[wid] = st
+        self.stream.load_arrays(arrays)
+        self.wf_state = {
+            wid: _WfView(self.workflows[wid], self.stream, wid,
+                         self._task_base[wid])
+            for wid in order}
         # Event plumbing + pool (one pickle: VM identity is preserved
         # between running pipelines, vm_bound and the pool's own maps).
         self.events = residue["events"]
@@ -1427,7 +1214,10 @@ class SimState:
 
 
 class SimEngine(SimState):
-    """One policy × one workload → SimResult (sequential driver)."""
+    """One policy × one workload → SimResult: the sequential oracle.
+
+    Every scheduling cycle runs :meth:`SimState.sequential_cycle`, so the
+    oracle never calls the affinity kernel it checks."""
 
     def __init__(
         self,
@@ -1436,32 +1226,21 @@ class SimEngine(SimState):
         workflows: Sequence[Workflow],
         seed: int = 0,
         trace: bool = False,
-        batched: object = "auto",
         predistributed: Optional[Dict[int, float]] = None,
-        redistribute: str = "finish",
-        soa: Optional[bool] = None,
         profile: Optional[bool] = None,
         events: Union[None, bool, EventLog] = None,
         chaos: Optional[ChaosConfig] = None,
         monitor: Union[None, bool, "obs_monitor.Monitor"] = None,
     ):
-        """``batched``: True / False / "auto" — use the JAX batched
-        scheduling cycle (core.jax_cycles) when the queue×pool product is
-        large.  EBPSM-family policies only; MSLBL mutates spare budget
-        mid-cycle and stays sequential.
-
-        ``profile`` / ``events``: per-engine toggles for the phase
+        """``profile`` / ``events``: per-engine toggles for the phase
         counters and the structured event log (None defers to
         ``REPRO_PROFILE`` / ``REPRO_TRACE``; see :class:`SimState`).
 
         ``chaos``: fault-injection knobs (:class:`repro.chaos.ChaosConfig`);
         None or all-zero ⇒ the benign engine, bit-for-bit."""
         super().__init__(cfg, policy, workflows, seed=seed, trace=trace,
-                         predistributed=predistributed,
-                         redistribute=redistribute, soa=soa,
-                         profile=profile, events=events, chaos=chaos,
-                         monitor=monitor)
-        self.batched = batched
+                         predistributed=predistributed, profile=profile,
+                         events=events, chaos=chaos, monitor=monitor)
 
     # ---- main loop -----------------------------------------------------------
     def run(self) -> SimResult:
@@ -1469,37 +1248,9 @@ class SimEngine(SimState):
         self.seed_arrivals()
         while self.events:
             if self.advance():
-                self._schedule_cycle()
+                self.sequential_cycle()
                 self.post_cycle()
         return self.finalize(wall_s=_time.time() - t0)
-
-    # ---- scheduling cycle (Alg. 2 driver) ------------------------------------
-    def _use_batched(self, n_queue: int, n_idle: int) -> bool:
-        if self.policy.budget_mode != "ebpsm":
-            return False
-        if self.batched is True:
-            return True
-        if self.batched == "auto":
-            return n_queue * n_idle >= AUCTION_MIN_PAIRS
-        return False
-
-    def _schedule_cycle(self) -> None:
-        idle = self.pool.idle_vms()
-        if self.queue and self._use_batched(len(self.queue), len(idle)):
-            self._schedule_cycle_batched(idle)
-            return
-        self.sequential_cycle(idle)
-
-    def _schedule_cycle_batched(self, idle: List[VM]) -> None:
-        """Whole-queue scheduling via the JAX affinity kernel + auction
-        (core.jax_cycles).  Matches the sequential outcome exactly while
-        budgets are sufficient (see jax_cycles docstring)."""
-        from .jax_cycles import batched_cycle
-
-        tasks, metas, tables = self.drain_queue_for_cycle()
-        placements = batched_cycle(self.cfg, self.policy, tasks, idle,
-                                   self.pool, tables=tables)
-        self.apply_cycle_placements(metas, placements, idle)
 
 
 def simulate(

@@ -25,23 +25,19 @@ static, every later round of that auction sends only a small live mask
 (the rows still unplaced and the VMs still free), which the kernel folds
 into the tier.
 
-Two drivers consume the auction:
-
-* :func:`batched_cycle` — one simulation's cycle (used by ``SimEngine``
-  when the queue×pool product is large);
-* :func:`multi_cycle` — many independent simulations' cycles at once
-  (used by ``core.jax_engine.BatchSimEngine``): each round stacks every
-  active member's proposal into one ``[B, T, V]`` tensor and scores it
-  with a single kernel call whose grid runs over the member dim.
+One driver consumes the auction: :func:`multi_cycle` runs many
+independent simulations' cycles at once (``core.jax_engine.BatchSimEngine``;
+a single simulation is a one-member grid): each round stacks every active
+member's proposal into one ``[B, T, V]`` tensor and scores it with a
+single kernel call whose grid runs over the member dim.
 
 Tuning knobs (see the README "Tuning knobs" table): ``AUCTION_TAIL_PAIRS``
 (=192) drains a member's auction tail through per-task ``select`` once
 its remaining queue×pool product drops below it — identical outcomes
 (the fixed point *is* the sequential interleaving), it just stops paying
-per-round kernel dispatch for a handful of pairs.  The thresholds that
-decide whether a cycle rides this module at all
-(``AUCTION_MIN_PAIRS_ROUND``, legacy ``AUCTION_MIN_PAIRS_GRID``) live in
-``core.jax_engine``.
+per-round kernel dispatch for a handful of pairs.  The threshold that
+decides whether a cycle rides this module at all
+(``AUCTION_MIN_PAIRS_ROUND``) lives in ``core.jax_engine``.
 """
 from __future__ import annotations
 
@@ -547,16 +543,3 @@ def multi_cycle(cfg: PlatformConfig, requests: Sequence[CycleRequest],
             ph.close()
     return [r.placements for r in requests]
 
-
-def batched_cycle(cfg: PlatformConfig, policy: Policy,
-                  tasks, vms: Sequence[VM], pool: VMPool,
-                  use_pallas: object = "auto", tables=None
-                  ) -> List[Optional[Placement]]:
-    """Returns, per task (queue order), a reuse Placement or None (task
-    needs the provisioning fallback)."""
-    if not tasks:
-        return []
-    if not vms:
-        return [None] * len(tasks)
-    req = CycleRequest(cfg, policy, tasks, vms, pool, tables=tables)
-    return multi_cycle(cfg, [req], use_pallas=use_pallas)[0]

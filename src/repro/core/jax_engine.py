@@ -62,8 +62,7 @@ import numpy as np
 
 from . import budget as budget_mod
 from ..chaos import ChaosConfig
-from .engine import (STREAM_SNAPSHOT_VERSION, SimState,
-                     _object_state_forced, _profile_enabled,
+from .engine import (STREAM_SNAPSHOT_VERSION, SimState, _profile_enabled,
                      new_engine_profile, new_profile, phase)
 from .jax_cycles import KERNEL_COUNTERS, CycleRequest, multi_cycle
 from ..obs import events as obs_events
@@ -77,20 +76,13 @@ from .types import PlatformConfig, SimResult, StreamState, Workflow, \
 # One grid member: (policy, workflows, degradation seed).
 GridMember = Tuple[Policy, Sequence[Workflow], int]
 
-# Legacy per-member auction threshold (queue × pool pairs), kept for the
-# ``batched="member"`` compatibility mode the grid-wall benchmark uses as
-# its measured baseline.  The default dispatcher decides on *aggregate*
-# round size instead (below).
-AUCTION_MIN_PAIRS_GRID = 2048
-
 # Aggregate-round auction threshold: at each rendezvous the driver sums
 # every parked member's queue × pool pair product and rides one batched
 # ``multi_cycle`` whenever the round total clears this; below it every
 # parked cycle runs per task.  ``ROUND_COUNTERS`` count the rounds and
-# their pairs on each side.  Much lower than the per-member threshold —
-# one resident [B, T, V] kernel call amortizes across all parked members,
-# so dozens of small cycles that individually never justified a device
-# call batch into one.  The value was set on the CPU.  The chip readings for retuning it
+# their pairs on each side.  One resident [B, T, V] kernel call amortizes
+# across all parked members, so dozens of small cycles that individually
+# never justify a device call batch into one.  The value was set on the CPU.  The chip readings for retuning it
 # are the benchmark's ``serial_us_per_pair`` and ``auction_us_per_pair``
 # in ``grid-montage-lowrate`` (most rounds parked) and ``grid-montage``
 # (most ridden): the average host cost of a pair on each side.  They
@@ -127,7 +119,6 @@ class BatchSimEngine:
         batched: object = "auto",
         predistributed: Optional[Sequence[Optional[Dict[int, float]]]] = None,
         redistribute: str = "finish",
-        soa: Optional[bool] = None,
         profile: Optional[bool] = None,
         events: Optional[bool] = None,
         chaos: Optional[ChaosConfig] = None,
@@ -135,7 +126,7 @@ class BatchSimEngine:
         monitor_maps: Optional[Tuple[Dict[int, str], Dict[str, str],
                                      Dict[int, int]]] = None,
     ):
-        """``batched``: False / True / "auto" / "member".
+        """``batched``: False / True / "auto".
 
         * ``"auto"`` (default) — the aggregate-round dispatcher: members
           park at every EBPSM scheduling cycle; a rendezvous round rides
@@ -144,8 +135,6 @@ class BatchSimEngine:
           parked cycle runs the per-task reference path.
         * ``True`` — every parked round is auctioned; ``False`` — members
           never park (pure sequential reference, one slice per member).
-        * ``"member"`` — the pre-aggregate per-member rule (pairs ≥
-          ``AUCTION_MIN_PAIRS_GRID``), kept as the benchmark baseline.
 
         Outcomes are bit-exact with ``SimEngine`` on every path,
         including insufficient-budget tier-5 cycles.
@@ -158,16 +147,12 @@ class BatchSimEngine:
         workloads whose arrival-time budget distribution already ran (see
         ``predistribute_workload`` / ``SimState``).
 
-        ``redistribute``: ``"finish"`` (default, per-task-finish Algorithm
-        3, bit-exact with ``SimEngine``) or ``"round"`` — each member
-        banks finish surpluses and redistributes once per workflow per
-        scheduling cycle, so all finish events inside one rendezvous
-        round coalesce into a single array call (shared ``SimState``
-        semantics: engine↔engine parity holds in both modes).
+        ``redistribute``: the Algorithm-3 trigger, which is per task
+        finish (``"finish"``, the paper's rule); any other value raises
+        ``ValueError``.  Kept so that a caller can state the guarantee.
 
-        ``soa``: state layout (see ``SimState``).  In SoA mode (the
-        default) the engine allocates ONE pooled :class:`StreamState`
-        spanning every member and hands each ``SimState`` a zero-copy
+        The engine allocates ONE pooled :class:`StreamState` spanning
+        every member and hands each ``SimState`` a zero-copy
         :meth:`StreamState.view` segment — thousands of open-stream
         members share a handful of flat numpy arrays instead of carrying
         per-member object graphs, and driver-level aggregates
@@ -188,26 +173,23 @@ class BatchSimEngine:
         applied to every member — each member's draws are keyed by its own
         seed, and injections stay bit-exact with a ``SimEngine`` run of
         the same (policy, workflows, seed, chaos)."""
+        if redistribute != "finish":
+            raise ValueError(f"redistribute={redistribute!r} (Algorithm 3 "
+                             "runs per task finish: expected 'finish')")
         self.cfg = cfg
         self.use_pallas = use_pallas
         self.batched = batched
-        self.redistribute = redistribute
         pre = predistributed or [None] * len(members)
-        soa_resolved = (not _object_state_forced()) if soa is None \
-            else bool(soa)
-        self.stream: Optional[StreamState] = None
-        views: List[Optional[StreamState]] = [None] * len(members)
-        if soa_resolved and members:
-            wf_counts = [len(wfs) for _, wfs, _ in members]
-            task_counts = [sum(w.n_tasks for w in wfs)
-                           for _, wfs, _ in members]
-            self.stream = StreamState(sum(wf_counts), sum(task_counts))
-            wf_lo = task_lo = 0
-            for i, (nw, nt) in enumerate(zip(wf_counts, task_counts)):
-                views[i] = self.stream.view(wf_lo, wf_lo + nw,
-                                            task_lo, task_lo + nt)
-                wf_lo += nw
-                task_lo += nt
+        wf_counts = [len(wfs) for _, wfs, _ in members]
+        task_counts = [sum(w.n_tasks for w in wfs) for _, wfs, _ in members]
+        self.stream = StreamState(sum(wf_counts), sum(task_counts))
+        views: List[StreamState] = []
+        wf_lo = task_lo = 0
+        for nw, nt in zip(wf_counts, task_counts):
+            views.append(self.stream.view(wf_lo, wf_lo + nw,
+                                          task_lo, task_lo + nt))
+            wf_lo += nw
+            task_lo += nt
         ev_enabled = (obs_events._trace_enabled() if events is None
                       else bool(events))
         self.elog: Optional[EventLog] = EventLog() if ev_enabled else None
@@ -221,8 +203,7 @@ class BatchSimEngine:
         t_of, q_of, i_ms = monitor_maps or (None, None, None)
         self.states = [
             SimState(cfg, policy, workflows, seed=seed, trace=trace,
-                     predistributed=p, redistribute=redistribute,
-                     soa=soa_resolved, stream=v, profile=profile,
+                     predistributed=p, stream=v, profile=profile,
                      events=ev_enabled, chaos=chaos,
                      monitor=(obs_monitor.Monitor(tenant_of=t_of,
                                                   qos_of=q_of,
@@ -235,8 +216,7 @@ class BatchSimEngine:
         self.batched_calls = 0
         self.batched_cycles = 0     # member-cycles scored by the kernel
         self.serial_cycles = 0      # parked member-cycles run per-task
-        self.round_pairs: List[int] = []          # aggregate pairs / round
-        self.batched_member_pairs: List[int] = []  # per-member pairs when batched
+        self.round_pairs: List[int] = []   # aggregate pairs / round
         # Kernel rounds of every auction (jax_cycles.KERNEL_COUNTERS).
         self.kernel_stats: Dict[str, int] = dict.fromkeys(KERNEL_COUNTERS, 0)
         # The dispatcher's decisions (ROUND_COUNTERS).
@@ -272,8 +252,7 @@ class BatchSimEngine:
         """The dispatcher: which parked cycles of this round are auctioned.
         In ``"auto"`` mode the whole round rides the kernel when its
         summed pairs reach ``AUCTION_MIN_PAIRS_ROUND``, else every cycle
-        runs per task; ``True`` rides every cycle with pairs, ``"member"``
-        each cycle at ``AUCTION_MIN_PAIRS_GRID`` on its own.  Zero-pair
+        runs per task; ``True`` rides every cycle with pairs.  Zero-pair
         cycles (no idle VMs — pure provisioning fallback) never ride: the
         kernel has nothing to score for them.
 
@@ -285,8 +264,6 @@ class BatchSimEngine:
         self.round_pairs.append(total)
         if self.batched is True:
             rides = [p > 0 for p in pairs]
-        elif self.batched == "member":
-            rides = [p >= AUCTION_MIN_PAIRS_GRID for p in pairs]
         else:
             # "auto": one aggregate decision for the whole rendezvous round.
             ride = total >= AUCTION_MIN_PAIRS_ROUND
@@ -344,7 +321,6 @@ class BatchSimEngine:
                                                                     pairs)):
                 if ride:
                     self.batched_cycles += 1
-                    self.batched_member_pairs.append(p)
                     ride_pairs += p
                     ph = phase(prof, "auction.build") if prof is not None \
                         else None
@@ -418,7 +394,6 @@ class BatchSimEngine:
                 "batched_cycles": self.batched_cycles,
                 "serial_cycles": self.serial_cycles,
                 "round_pairs": self.round_pairs,
-                "batched_member_pairs": self.batched_member_pairs,
                 "kernel_stats": self.kernel_stats,
                 "round_stats": self.round_stats,
                 "profile": self.profile,
@@ -457,7 +432,6 @@ class BatchSimEngine:
         self.batched_cycles = c["batched_cycles"]
         self.serial_cycles = c["serial_cycles"]
         self.round_pairs = list(c["round_pairs"])
-        self.batched_member_pairs = list(c["batched_member_pairs"])
         # Snapshots from before the kernel or round counters (or some of
         # them) and the engine block, or some of its keys, lack them; the
         # engine keeps what its constructor made.
@@ -473,25 +447,12 @@ class BatchSimEngine:
 
     def stream_stats(self) -> Dict[str, float]:
         """Whole-stream aggregates reduced straight off the pooled
-        StreamState arrays (no per-member iteration); falls back to the
-        per-state objects under ``REPRO_OBJECT_STATE=1``."""
-        if self.stream is not None:
-            arrived = int(self.stream.arrived.sum())
-            open_wfs = int((self.stream.arrived
-                            & (self.stream.remaining > 0)).sum())
-            tasks_left = int(self.stream.remaining.sum())
-            spare = float(self.stream.spare.sum())
-        else:
-            arrived = open_wfs = tasks_left = 0
-            spare = 0.0
-            for st in self.states:
-                for wst in st.wf_state.values():
-                    arrived += 1
-                    open_wfs += wst.remaining > 0
-                    tasks_left += wst.remaining
-                    spare += wst.spare
-        return {"workflows_arrived": arrived, "workflows_open": open_wfs,
-                "tasks_remaining": tasks_left, "spare_budget": spare}
+        StreamState arrays (no per-member iteration)."""
+        ss = self.stream
+        return {"workflows_arrived": int(ss.arrived.sum()),
+                "workflows_open": int((ss.arrived & (ss.remaining > 0)).sum()),
+                "tasks_remaining": int(ss.remaining.sum()),
+                "spare_budget": float(ss.spare.sum())}
 
     def dispatch_stats(self) -> Dict[str, object]:
         """The dispatcher's record for benchmarks and reports: rounds,
@@ -506,15 +467,10 @@ class BatchSimEngine:
             hist[key] = hist.get(key, 0) + 1
         out: Dict[str, object] = {
             "rounds": self.rounds,
-            "redistribute_mode": self.redistribute,
             "batched_calls": self.batched_calls,
             "batched_cycles": self.batched_cycles,
             "serial_cycles": self.serial_cycles,
             "aggregate_pairs_hist": hist,
-            "max_member_pairs_batched": max(self.batched_member_pairs,
-                                            default=0),
-            "min_member_pairs_batched": min(self.batched_member_pairs,
-                                            default=0),
             **self.round_stats,
             **self.kernel_stats,
         }
@@ -628,8 +584,6 @@ def simulate_batch(
     trace: bool = False,
     use_pallas: object = "auto",
     batched: object = "auto",
-    redistribute: str = "finish",
-    soa: Optional[bool] = None,
     profile: Optional[bool] = None,
     events: Optional[bool] = None,
     chaos: Optional[ChaosConfig] = None,
@@ -664,7 +618,6 @@ def simulate_batch(
                 pre.append(spares)
     engine = BatchSimEngine(cfg, members, trace=trace, use_pallas=use_pallas,
                             batched=batched, predistributed=pre,
-                            redistribute=redistribute, soa=soa,
                             profile=profile, events=events, chaos=chaos)
     results = engine.run()
     entries = [

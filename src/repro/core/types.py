@@ -236,15 +236,14 @@ class StreamState:
     per-task mutable scalars.
 
     The engines' hot bookkeeping — spare budget, accumulated cost,
-    unscheduled/remaining counts, finish clocks, round-mode surplus
-    banks, per-task pending-parent counters, the unscheduled mask, and
-    the Algorithm-3 ``RedistState`` pools (rank order, position index,
-    row mask, float64 budget mirror) — lives in flat numpy arrays
-    indexed by wid (per-workflow fields) or by task global id
-    (per-task fields), instead of one Python object graph per workflow.
-    ``core.engine`` reads and writes it through thin per-workflow
-    accessor views (``_WfView``) so the transition semantics stay
-    bit-exact with the legacy object path (``REPRO_OBJECT_STATE=1``).
+    unscheduled/remaining counts, finish clocks, per-task
+    pending-parent counters, the unscheduled mask, and the Algorithm-3
+    ``RedistState`` pools (rank order, position index, row mask, float64
+    budget mirror) — lives in flat numpy arrays indexed by wid
+    (per-workflow fields) or by task global id (per-task fields),
+    instead of one Python object graph per workflow.  ``core.engine``
+    reads and writes it through thin per-workflow accessor views
+    (``_WfView``).
 
     Two properties make it the unit of scale-out and checkpointing:
 
@@ -261,9 +260,8 @@ class StreamState:
 
     # (name, dtype): persisted per-workflow fields, indexed by wid.
     WF_FIELDS: Tuple[Tuple[str, str], ...] = (
-        ("spare", "f8"), ("cost", "f8"), ("pending_surplus", "f8"),
-        ("remaining", "i8"), ("finish_ms", "i8"), ("pending_events", "i8"),
-        ("arrived", "?"),
+        ("spare", "f8"), ("cost", "f8"), ("remaining", "i8"),
+        ("finish_ms", "i8"), ("arrived", "?"),
     )
     # Persisted per-task fields, indexed by task global id.
     TASK_FIELDS: Tuple[Tuple[str, str], ...] = (

@@ -84,10 +84,7 @@ def _merge_stats(parts: List[Dict]) -> Dict:
     """Combine per-engine ``dispatch_stats`` payloads."""
     summed = ("rounds", "batched_calls", "batched_cycles", "serial_cycles",
               *ROUND_COUNTERS, *KERNEL_COUNTERS)
-    out: Dict = {**dict.fromkeys(summed, 0), "aggregate_pairs_hist": {},
-                 "max_member_pairs_batched": 0,
-                 "min_member_pairs_batched": 0}
-    mins = []
+    out: Dict = {**dict.fromkeys(summed, 0), "aggregate_pairs_hist": {}}
     profiles: List[Dict] = []
     for s in parts:
         for k in summed:
@@ -95,13 +92,8 @@ def _merge_stats(parts: List[Dict]) -> Dict:
         for b, n in s["aggregate_pairs_hist"].items():
             out["aggregate_pairs_hist"][b] = \
                 out["aggregate_pairs_hist"].get(b, 0) + n
-        out["max_member_pairs_batched"] = max(
-            out["max_member_pairs_batched"], s["max_member_pairs_batched"])
-        if s["batched_cycles"]:
-            mins.append(s["min_member_pairs_batched"])
         if "profile" in s:
             profiles.append(s["profile"])
-    out["min_member_pairs_batched"] = min(mins) if mins else 0
     # Structured-event counts (repro.obs): totals and per-kind counts sum
     # across engines exactly like the phase counters, so a --workers run
     # merges to the same block as a serial run of the same chunking
@@ -125,10 +117,6 @@ def _merge_stats(parts: List[Dict]) -> Dict:
     mon_parts = [s["monitor"] for s in parts if "monitor" in s]
     if mon_parts:
         out["monitor"] = obs_monitor.merge_monitor_blocks(mon_parts)
-    if parts:
-        # Uniform across parts — every engine in a run shares the mode.
-        out["redistribute_mode"] = parts[0].get("redistribute_mode",
-                                                "finish")
     if profiles:
         # REPRO_PROFILE=1 phase counters: sum the absolute seconds
         # (including the per-engine walls); the artifact assembler
@@ -157,7 +145,6 @@ def _grid_batch(
     trace: bool,
     use_pallas: object,
     batched: object,
-    redistribute: str = "finish",
     events: bool = False,
     trace_dir: Optional[str] = None,
     report_dir: Optional[str] = None,
@@ -195,7 +182,6 @@ def _grid_batch(
     mon_on = bool(monitor or report_dir)
     engine = BatchSimEngine(cfg, members, trace=trace, predistributed=pre,
                             use_pallas=use_pallas, batched=batched,
-                            redistribute=redistribute,
                             events=bool(events or trace_dir or mon_on),
                             monitor=mon_on or None)
     results = engine.run()
@@ -230,7 +216,6 @@ def run_grid(
     workers: int = 1,
     use_pallas: object = "auto",
     batched: object = "auto",
-    redistribute: str = "finish",
     executor=None,
     events: bool = False,
     trace_dir: Optional[str] = None,
@@ -243,8 +228,8 @@ def run_grid(
     (spawn context — safe with an initialized JAX runtime in the
     parent).  CPU backend only: on a TPU the chip belongs to one
     process, so ``workers > 1`` is refused there.  ``executor`` lets
-    callers reuse a warm pool across runs (the grid-wall benchmark
-    does); it must come from ``grid_executor(workers)``.
+    callers reuse a warm pool across runs; it must come from
+    ``grid_executor(workers)``.
 
     ``events`` enables structured-event collection (the artifact's
     ``dispatch.events`` block); ``trace_dir`` additionally writes one
@@ -279,8 +264,7 @@ def run_grid(
         ex = executor or grid_executor(workers)
         try:
             futs = [ex.submit(_grid_batch, scenario, cfg, b, trace,
-                              use_pallas, batched, redistribute,
-                              events, trace_dir, report_dir, monitor)
+                              use_pallas, batched, events, trace_dir, report_dir, monitor)
                     for b in batches]
             for i, f in enumerate(futs):
                 parts.append(f.result())
@@ -294,9 +278,8 @@ def run_grid(
     else:
         for batch in batches:
             parts.append(_grid_batch(scenario, cfg, batch, trace,
-                                     use_pallas, batched, redistribute,
-                                     events, trace_dir, report_dir,
-                                     monitor))
+                                     use_pallas, batched, events,
+                                     trace_dir, report_dir, monitor))
             if verbose:
                 done = sum(len(p[0]) for p in parts)
                 print(f"  {done}/{scenario.n_cells} cells "
@@ -306,7 +289,7 @@ def run_grid(
     stats = _merge_stats([s for _, s in parts])
     return _artifact(scenario, rows, stats,
                      wall_s=time.perf_counter() - t0, workers=workers,
-                     use_pallas=use_pallas, redistribute=redistribute)
+                     use_pallas=use_pallas)
 
 
 def _artifact(scenario, rows: List[Dict], stats: Dict, wall_s: float,
@@ -398,7 +381,6 @@ def run_online(
     verbose: bool = False,
     use_pallas: object = "auto",
     batched: object = "auto",
-    redistribute: str = "finish",
     ckpt_dir: Optional[str] = None,
     ckpt_every_s: Optional[float] = None,
     resume: bool = False,
@@ -457,11 +439,10 @@ def run_online(
             raise SystemExit(
                 f"checkpoint in {ckpt_dir} is for scenario "
                 f"{meta.get('scenario')!r}, not {scenario.name!r}")
-        if meta.get("redistribute") != redistribute:
+        if meta.get("redistribute", "finish") != "finish":
             raise SystemExit(
-                f"checkpoint was written with "
-                f"--redistribute {meta.get('redistribute')}, "
-                f"this run uses {redistribute}")
+                f"checkpoint was written with --redistribute "
+                f"{meta.get('redistribute')}, a mode that no longer exists")
         rows = list(meta.get("rows", []))
         stats_parts = list(meta.get("stats", []))
         start_seed_idx = int(meta.get("seed_index", 0))
@@ -488,7 +469,7 @@ def run_online(
             pre.append(spares)
         engine = BatchSimEngine(cfg, members, trace=trace,
                                 predistributed=pre, use_pallas=use_pallas,
-                                batched=batched, redistribute=redistribute,
+                                batched=batched,
                                 events=bool(events or trace_dir or mon_on),
                                 chaos=scenario.chaos,
                                 monitor=mon_on or None,
@@ -501,7 +482,6 @@ def run_online(
         if ckpt_dir and ckpt_every_s is not None:
             hook = _StreamCkpt(ckpt_dir, ckpt_every_s, meta={
                 "scenario": scenario.name,
-                "redistribute": redistribute,
                 "seed": seed,
                 "seed_index": seed_idx,
                 "rows": rows,
@@ -540,7 +520,6 @@ def run_online(
     return _artifact(
         scenario, rows, _merge_stats(stats_parts),
         wall_s=time.perf_counter() - t0, workers=1, use_pallas=use_pallas,
-        redistribute=redistribute,
         scenario_kind="online",
         warmup_s=scenario.warmup_s,
         p95_slowdown_ceiling=scenario.p95_slowdown_ceiling,
@@ -701,13 +680,6 @@ def main(argv: Optional[List[str]] = None) -> None:
                          "independent; the full paper grid parallelizes "
                          "across cores).  CPU backend only: refused on a "
                          "TPU, where the chip belongs to one process")
-    ap.add_argument("--redistribute", choices=("finish", "round"),
-                    default="finish",
-                    help="Algorithm-3 mode: per-task-finish (paper "
-                         "semantics, default) or round-batched (one "
-                         "pooled redistribution per workflow per "
-                         "scheduling cycle; coalesces surplus flows, "
-                         "A/B-gated — see docs/PROFILING.md)")
     ap.add_argument("--check-floors", action="store_true",
                     help="exit non-zero on budget-met floor / makespan-win "
                          "regressions")
@@ -759,7 +731,6 @@ def main(argv: Optional[List[str]] = None) -> None:
               f"warm-up {scenario.warmup_s:.0f}s)")
         try:
             art = run_online(scenario, verbose=True,
-                             redistribute=args.redistribute,
                              ckpt_dir=args.ckpt_dir,
                              ckpt_every_s=args.ckpt_every_s,
                              resume=args.resume,
@@ -781,7 +752,6 @@ def main(argv: Optional[List[str]] = None) -> None:
               + (f", {args.workers} workers" if args.workers > 1 else ""))
         art = run_grid(scenario, cells_per_batch=args.cells_per_batch,
                        verbose=True, workers=args.workers,
-                       redistribute=args.redistribute,
                        events=args.trace_events, trace_dir=args.trace_dir,
                        report_dir=args.report_dir)
     if args.trace_dir:

@@ -14,8 +14,7 @@ Turns an :class:`~repro.obs.events.EventLog` into:
 
 Both are **byte-deterministic** in the event log: keys sorted, compact
 separators, no wall-clock or host fields — the same cell + seed produces
-identical bytes across runs, state layouts (SoA vs object) and
-checkpoint/resume cuts (gated in ``tests/test_obs.py``, validated by
+identical bytes across runs, engines and checkpoint/resume cuts (gated in ``tests/test_obs.py``, validated by
 ``tools/check_trace.py``).  Simulated milliseconds map to trace
 microseconds (Chrome's ``ts`` unit) as ``ts = t_ms * 1000``.
 """

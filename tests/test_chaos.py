@@ -4,7 +4,7 @@ The contract under test (PR 9):
 
 * **determinism** — injection is a pure function of ``(seed, config)``:
   repeat runs produce identical results, counters and event streams;
-* **parity** — ``SimEngine`` (object and SoA layouts) and
+* **parity** — ``SimEngine`` and
   ``BatchSimEngine`` agree bit-exactly under revocations, failures and
   stragglers, and a stream interrupted/resumed through a revocation
   round finishes bit-exact with the uninterrupted run;
@@ -123,7 +123,7 @@ def test_chaos_seed_changes_injection():
 
 
 # ---------------------------------------------------------------------------
-# Engine / layout parity
+# Engine parity
 # ---------------------------------------------------------------------------
 
 
@@ -132,12 +132,6 @@ def test_chaos_engine_parity_sim_vs_batch(policy):
     _, ref = run_one(policy)
     beng = BatchSimEngine(CFG, [(policy, workload(0), 0)], chaos=CHAOS)
     assert signature(beng.run()[0]) == signature(ref)
-
-
-def test_chaos_object_vs_soa_parity():
-    _, soa = run_one(soa=True)
-    _, obj = run_one(soa=False)
-    assert signature(soa) == signature(obj)
 
 
 def test_chaos_scalar_vs_vector_redistribution(monkeypatch):

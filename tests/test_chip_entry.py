@@ -1,7 +1,6 @@
 """The chip entry points off the chip: ``chip_smoke.py`` refuses without a
-TPU, the grid-wall benchmark starts no worker pool on a TPU, and the
-compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, else to a
-fixed directory inside the repository."""
+TPU, and the compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says,
+else to a fixed directory inside the repository."""
 import os
 import shutil
 import subprocess
@@ -54,20 +53,3 @@ def test_compile_cache_defaults_to_repo(monkeypatch):
         jax.config.update("jax_compilation_cache_dir", before)
     assert ".jax_cache/" in (ROOT / ".gitignore").read_text().split()
 
-
-def test_grid_wall_skips_workers_on_tpu(monkeypatch):
-    from benchmarks import bench_grid_wall as bgw
-
-    def no_pool(n):
-        raise AssertionError("worker pool started on a TPU backend")
-
-    art = {"n_cells": 1, "dispatch": {"batched_calls": 1, "batched_cycles": 1,
-                                      "min_member_pairs_batched": 10}}
-    monkeypatch.setattr(bgw.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(bgw, "run_grid", lambda *a, **k: art)
-    monkeypatch.setattr(bgw, "_best_wall", lambda fn, repeats=3: 1.0)
-    monkeypatch.setattr(bgw, "_measure_redistribution", lambda: {})
-    monkeypatch.setattr(bgw, "grid_executor", no_pool)
-    out = bgw._measure()
-    assert out["wall_workers_s"] is None and out["workers"] == 1
-    assert "tpu" in out["workers_skipped"]

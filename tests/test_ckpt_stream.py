@@ -2,8 +2,8 @@
 
 The contract under test (PR 7):
 
-* ``SimState.snapshot`` / ``load_snapshot`` — a layout-independent cut of
-  one simulation (SoA snapshots restore into object layout and back);
+* ``SimState.snapshot`` / ``load_snapshot`` — a cut of one simulation;
+  an older v2 cut that banked round-mode Algorithm-3 surplus is refused;
 * ``BatchSimEngine.snapshot`` / ``load_snapshot`` — the whole grid at a
   rendezvous-round boundary; a fresh engine restored from the cut and
   run to completion is bit-exact with the uninterrupted run, wherever
@@ -58,24 +58,36 @@ def _signatures(results):
 # ---------------------------------------------------------------------------
 
 
-def test_save_restore_stream_roundtrip(tmp_path):
-    snap = {
-        "arrays": {
-            "m0000.spare": np.array([1.5, 0.25], dtype=np.float64),
-            "m0000.remaining": np.array([3, 0], dtype=np.int64),
-            "m0000.arrived": np.array([True, False]),
-        },
-        "residue": b"\x00opaque-bytes\xff",
-        "version": 1,
-        "n_members": 1,
-    }
+# The rounds of a full run of the ``_members()`` grid.
+_GRID_ROUNDS = []
+
+
+def _grid_rounds() -> int:
+    if not _GRID_ROUNDS:
+        eng = BatchSimEngine(CFG, _members())
+        eng.run()
+        _GRID_ROUNDS.append(eng.rounds)
+    return _GRID_ROUNDS[0]
+
+
+@pytest.mark.parametrize("cut_round", [1, 3, "late"])
+def test_save_restore_stream_roundtrip(tmp_path, cut_round):
+    """A grid cut at a rendezvous round round-trips through
+    ``save_stream`` / ``restore_stream``: every named array with its
+    dtype and values, the residue bytes, the member count, the version
+    and the meta."""
+    if cut_round == "late":
+        cut_round = _grid_rounds() - 1
+        assert cut_round > 3
+    snap = _interrupt_at(BatchSimEngine(CFG, _members()), cut_round)
     meta = {"scenario": "x", "rows": [{"a": 0.125}]}
     ckpt.save_stream(str(tmp_path), 4, snap, meta=meta)
     assert ckpt.latest_step(str(tmp_path)) == 4
     back, step, meta2 = ckpt.restore_stream(str(tmp_path))
     assert step == 4 and meta2 == meta
     assert back["residue"] == snap["residue"]
-    assert back["n_members"] == 1
+    assert back["n_members"] == snap["n_members"] == 3
+    assert back["version"] == snap["version"]
     assert set(back["arrays"]) == set(snap["arrays"])
     for name, arr in snap["arrays"].items():
         got = back["arrays"][name]
@@ -152,21 +164,6 @@ def test_interrupt_resume_through_disk(tmp_path):
     assert _signatures(eng2.run()) == want
 
 
-@pytest.mark.parametrize("src_soa,dst_soa", [(True, False), (False, True)],
-                         ids=["soa-to-object", "object-to-soa"])
-def test_snapshot_layout_interchange(src_soa, dst_soa):
-    """Snapshots are layout-independent: a cut taken in one state layout
-    restores into the other and still finishes bit-exact."""
-    ref = BatchSimEngine(CFG, _members())
-    want = _signatures(ref.run())
-
-    eng = BatchSimEngine(CFG, _members(), soa=src_soa)
-    snap = _interrupt_at(eng, 4)
-    eng2 = BatchSimEngine(CFG, _members(), soa=dst_soa)
-    eng2.load_snapshot(snap)
-    assert _signatures(eng2.run()) == want
-
-
 def test_load_snapshot_rejects_member_count_mismatch():
     eng = BatchSimEngine(CFG, _members((0, 1, 2)))
     snap = _interrupt_at(eng, 1)
@@ -183,6 +180,27 @@ def test_simstate_snapshot_version_gate():
     fresh = SimEngine(CFG, EBPSM, workload(7, n=3), seed=0)
     with pytest.raises(ValueError):
         fresh.load_snapshot(snap)
+
+
+def test_v2_snapshot_with_banked_round_surplus_refused():
+    """An older v2 cut carries the round-mode columns ``pending_surplus``
+    and ``pending_events``.  With none banked it restores (the columns
+    are ignored) and finishes bit-exact; with a finish banked it came from
+    the retired round mode and is refused."""
+    eng = BatchSimEngine(CFG, _members())
+    want = _signatures(BatchSimEngine(CFG, _members()).run())
+    snap = _interrupt_at(eng, 3)
+    for i in range(3):
+        n_wf = len(snap["arrays"][f"m{i:04d}.spare"])
+        snap["arrays"][f"m{i:04d}.pending_surplus"] = np.zeros(n_wf)
+        snap["arrays"][f"m{i:04d}.pending_events"] = np.zeros(n_wf, np.int64)
+    eng2 = BatchSimEngine(CFG, _members())
+    eng2.load_snapshot(snap)
+    assert _signatures(eng2.run()) == want
+    snap["arrays"]["m0001.pending_events"][0] = 2
+    snap["arrays"]["m0001.pending_surplus"][0] = 0.5
+    with pytest.raises(ValueError, match="round-mode"):
+        BatchSimEngine(CFG, _members()).load_snapshot(snap)
 
 
 # ---------------------------------------------------------------------------

@@ -35,10 +35,9 @@ def _run(batched, order=None, **kw):
     return eng, results
 
 
-@pytest.mark.parametrize("batched", ["auto", "member", True])
+@pytest.mark.parametrize("batched", ["auto", True])
 def test_each_round_with_pairs_is_parked_or_ridden(monkeypatch, batched):
     monkeypatch.setattr(je, "AUCTION_MIN_PAIRS_ROUND", THRESHOLD)
-    monkeypatch.setattr(je, "AUCTION_MIN_PAIRS_GRID", THRESHOLD // 2)
     eng, _ = _run(batched)
     c = eng.dispatch_stats()
     assert c["parked_pairs"] + c["ridden_pairs"] == sum(eng.round_pairs)

@@ -3,19 +3,23 @@ bit-exact with the sequential reference.
 
 Axes covered:
 
-* ``batched`` ∈ {False, True, "auto" (aggregate-round), "member"
-  (legacy per-member threshold)} on randomized mixed grids — EBPSM
-  family + MSLBL members, sufficient and insufficient budgets;
+* ``batched`` ∈ {False, True, "auto" (aggregate-round)} on randomized
+  mixed grids — EBPSM family + MSLBL members, sufficient and
+  insufficient budgets;
 * ``use_pallas`` ∈ {False (jnp oracle), True (Pallas, interpreted on
   CPU)};
 * ``select`` scalar loop (``REPRO_SCALAR_SELECT`` oracle) vs the
   vectorized numpy path;
 * the small-subset pure-Python budget distribution vs the numpy branch;
 * aggregate-round engagement itself: the auction must fire on rounds
-  whose individual members sit below the legacy 2048-pair threshold.
+  whose individual members sit below ``AUCTION_MIN_PAIRS_ROUND``, and a
+  round parks or rides by its summed pairs against that threshold;
+* the pooled ``StreamState``: a grid member's segment ends equal to a
+  solo ``SimEngine``'s arrays.
 """
 import random
 
+import numpy as np
 import pytest
 
 import repro.core.budget as budget_mod
@@ -27,7 +31,7 @@ from repro.core.jax_cycles import _RoundBuffers
 from repro.core.jax_engine import BatchSimEngine
 from repro.core.scheduler import (ALL_POLICIES, EBPSM, EBPSM_NC, EBPSM_NS,
                                   EBPSM_WS, MSLBL_MW, select)
-from repro.core.types import PlatformConfig
+from repro.core.types import PlatformConfig, StreamState
 from repro.sim.cloud import VMPool
 from repro.workflows.workload import WorkloadSpec, generate_workload
 
@@ -65,9 +69,8 @@ def _mixed_members(rng):
     return members
 
 
-@pytest.mark.parametrize("batched", [False, True, "auto", "member"],
-                         ids=["serial", "forced", "aggregate-auto",
-                              "member-legacy"])
+@pytest.mark.parametrize("batched", [False, True, "auto"],
+                         ids=["serial", "forced", "aggregate-auto"])
 def test_dispatcher_matrix_randomized(batched, monkeypatch):
     """Mixed grids are bit-exact with per-member SimEngine references on
     every dispatcher path.  "auto" runs with a tiny aggregate threshold
@@ -101,36 +104,83 @@ def test_pallas_vs_jnp_paths(use_pallas):
     assert eng.batched_calls > 0
 
 
+def _spy_rounds(monkeypatch):
+    """Record each rendezvous round's per-member pairs and ride flags."""
+    seen = []
+    decide = BatchSimEngine._round_rides_kernel
+
+    def spy(self, points, pairs):
+        rides = decide(self, points, pairs)
+        seen.append((list(pairs), list(rides)))
+        return rides
+
+    monkeypatch.setattr(BatchSimEngine, "_round_rides_kernel", spy)
+    return seen
+
+
 def test_aggregate_engagement_below_member_threshold(monkeypatch):
     """The aggregate-round dispatcher's reason to exist: rounds engage
-    the kernel although every member is far below the legacy per-member
-    2048-pair threshold — and stay bit-exact."""
+    the kernel although every member's own pairs sit below the
+    threshold — and stay bit-exact."""
     monkeypatch.setattr(je, "AUCTION_MIN_PAIRS_ROUND", 64)
+    seen = _spy_rounds(monkeypatch)
     members = [(EBPSM, workload(s, n=5), s) for s in range(4)]
     eng = BatchSimEngine(CFG, members, batched="auto")
     results = eng.run()
     assert eng.batched_calls > 0
     assert eng.batched_cycles > 0
-    assert max(eng.batched_member_pairs) < 2048, \
-        "members this small must sit below the legacy threshold"
+    assert any(any(rides) and max(pairs) < 64 for pairs, rides in seen), \
+        "some round must ride on its summed pairs alone"
     stats = eng.dispatch_stats()
     assert stats["batched_calls"] == eng.batched_calls
-    assert stats["max_member_pairs_batched"] < 2048
     for (pol, _, seed), res, s in zip(members, results, range(4)):
         ref = SimEngine(CFG, pol, workload(s, n=5), seed=seed).run()
         assert_same(ref, res)
 
 
-def test_member_mode_keeps_legacy_gating():
-    """batched="member" reproduces the old rule: small members never
-    clear the per-member threshold, so no cycle rides the kernel."""
-    members = [(EBPSM, workload(s, n=4), s) for s in range(3)]
-    eng = BatchSimEngine(CFG, members, batched="member")
-    results = eng.run()
-    assert eng.batched_cycles == 0
-    for (pol, _, seed), res, s in zip(members, results, range(3)):
-        ref = SimEngine(CFG, pol, workload(s, n=4), seed=seed).run()
-        assert_same(ref, res)
+_FIRST_ROUND_PAIRS = []
+
+
+def _first_round_pairs(monkeypatch) -> int:
+    """Summed pairs of the grid's first rendezvous round with pairs (the
+    same whatever the threshold: every round before it has none)."""
+    if not _FIRST_ROUND_PAIRS:
+        with monkeypatch.context() as m:
+            seen = _spy_rounds(m)
+            members = [(EBPSM, workload(s, n=5), s) for s in range(4)]
+            BatchSimEngine(CFG, members, batched="auto").run()
+        _FIRST_ROUND_PAIRS.append(next(sum(p) for p, _ in seen if sum(p)))
+    return _FIRST_ROUND_PAIRS[0]
+
+
+@pytest.mark.parametrize("offset,side", [(1, "parked"), (0, "ridden"),
+                                         (-1, "ridden")],
+                         ids=["pairs-below", "pairs-at", "pairs-above"])
+def test_round_threshold_decides_by_summed_pairs(offset, side, monkeypatch):
+    """A round whose summed pairs lie below ``AUCTION_MIN_PAIRS_ROUND``
+    parks; at or above it, it rides — read from ``ROUND_COUNTERS`` after
+    that round, and the whole run stays bit-exact with ``SimEngine``."""
+    pairs = _first_round_pairs(monkeypatch)
+    assert pairs > 1
+    monkeypatch.setattr(je, "AUCTION_MIN_PAIRS_ROUND", pairs + offset)
+    first = {}
+
+    def hook(eng):
+        s = eng.round_stats
+        if not first and s["parked_rounds"] + s["ridden_rounds"] == 1:
+            first.update(s)
+        return False
+
+    members = [(EBPSM, workload(s, n=5), s) for s in range(4)]
+    eng = BatchSimEngine(CFG, members, batched="auto")
+    results = eng.run(ckpt_hook=hook)
+    assert first[side + "_rounds"] == 1
+    assert first[side + "_pairs"] == pairs
+    other = "parked" if side == "ridden" else "ridden"
+    assert first[other + "_rounds"] == first[other + "_pairs"] == 0
+    for (pol, _, seed), res, s in zip(members, results, range(4)):
+        ref = SimEngine(CFG, pol, workload(s, n=5), seed=seed).run()
+        assert_same(ref, res, f"threshold {pairs + offset}")
 
 
 # ---------------------------------------------------------------------------
@@ -284,39 +334,38 @@ def test_round_buffers_lru_cap():
 
 
 # ---------------------------------------------------------------------------
-# State layout: SoA StreamState vs legacy per-workflow objects
+# The pooled StreamState
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("batched", [False, "auto"],
                          ids=["serial", "aggregate-auto"])
-def test_state_layout_parity(batched, monkeypatch):
-    """SoA BatchSimEngine grids are bit-exact with *object-layout*
-    SimEngine references (and the cross pairing), on both dispatcher
-    paths — the state layout must be invisible to semantics."""
+def test_pooled_segment_matches_solo_stream(batched, monkeypatch):
+    """Each member's segment of the grid's pooled ``StreamState`` ends
+    equal to the arrays of a solo ``SimEngine`` run of that member, on
+    both dispatcher paths: the pooling is invisible to semantics."""
     if batched == "auto":
         monkeypatch.setattr(je, "AUCTION_MIN_PAIRS_ROUND", 16)
     members = _mixed_members(random.Random(4321))
     eng = BatchSimEngine(CFG, [(p, wl, s) for p, wl, s, *_ in members],
-                         batched=batched, soa=True)
+                         batched=batched)
     results = eng.run()
-    assert eng.stream is not None, "soa=True must allocate the pool"
+    fields = (StreamState.WF_FIELDS + StreamState.TASK_FIELDS
+              + StreamState.POOL_FIELDS)
+    wf_lo = task_lo = 0
     members2 = _mixed_members(random.Random(4321))
     for (pol, wl, seed, *_), res in zip(members2, results):
-        ref = SimEngine(CFG, pol, wl, seed=seed, soa=False).run()
-        assert_same(ref, res,
-                    f"{pol.name} seed={seed} batched={batched} soa-vs-obj")
-
-
-def test_object_state_escape_hatch(monkeypatch):
-    """REPRO_OBJECT_STATE=1 forces the legacy object layout on both
-    engines without touching call sites — and stays bit-exact with the
-    SoA default."""
-    wl = workload(11, n=5)
-    soa = BatchSimEngine(CFG, [(EBPSM, wl, 0)], soa=True)
-    assert soa.stream is not None
-    res_soa = soa.run()[0]
-    monkeypatch.setenv("REPRO_OBJECT_STATE", "1")
-    obj = BatchSimEngine(CFG, [(EBPSM, workload(11, n=5), 0)])
-    assert obj.stream is None, "hatch must suppress the pooled arrays"
-    assert_same(res_soa, obj.run()[0], "REPRO_OBJECT_STATE hatch")
+        ref = SimEngine(CFG, pol, wl, seed=seed)
+        assert_same(ref.run(), res, f"{pol.name} seed={seed}")
+        nw, nt = ref.stream.n_workflows, ref.stream.n_tasks
+        for name, _ in fields:
+            per_wf = (name, _) in StreamState.WF_FIELDS
+            lo, hi = (wf_lo, wf_lo + nw) if per_wf else \
+                (task_lo, task_lo + nt)
+            assert np.array_equal(getattr(eng.stream, name)[lo:hi],
+                                  getattr(ref.stream, name)), \
+                f"{pol.name} seed={seed} {name}"
+        wf_lo += nw
+        task_lo += nt
+    assert (wf_lo, task_lo) == (eng.stream.n_workflows,
+                                eng.stream.n_tasks)

@@ -338,8 +338,7 @@ def test_artifact_warns_on_dropped_events():
     stats = {"events": {"enabled": True, "total": 10, "by_kind": {},
                         "dropped": 7}}
     fake = exp_run._artifact(TINY_ONLINE, art["cells"], stats,
-                             wall_s=1.0, workers=1, use_pallas=False,
-                             redistribute="finish")
+                             wall_s=1.0, workers=1, use_pallas=False)
     assert len(fake["warnings"]) == 1
     assert "dropped 7 events" in fake["warnings"][0]
 

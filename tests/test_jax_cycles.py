@@ -27,10 +27,9 @@ def workload(seed):
                          ids=lambda p: p.name)
 @pytest.mark.parametrize("seed", [0, 3])
 def test_batched_equals_sequential(policy, seed):
-    seq = SimEngine(CFG, policy, workload(seed), seed=0,
-                    batched=False).run()
-    bat = SimEngine(CFG, policy, workload(seed), seed=0,
-                    batched=True).run()
+    seq = SimEngine(CFG, policy, workload(seed), seed=0).run()
+    bat = BatchSimEngine(CFG, [(policy, workload(seed), 0)],
+                         batched=True).run()[0]
     assert [w.finish_ms for w in seq.workflows] == \
         [w.finish_ms for w in bat.workflows]
     np.testing.assert_allclose([w.cost for w in seq.workflows],
@@ -69,7 +68,7 @@ def test_members_leaving_mid_auction_equal_sequential(policy, monkeypatch):
             for member, live in masks if isinstance(live, np.ndarray)]
     assert any(left), "no round had one member left and another still in"
     for (p, wl), res in zip(members(), results):
-        ref = SimEngine(CFG, p, wl, seed=0, batched=False).run()
+        ref = SimEngine(CFG, p, wl, seed=0).run()
         assert [w.finish_ms for w in ref.workflows] == \
             [w.finish_ms for w in res.workflows]
         assert [w.cost for w in ref.workflows] == \
@@ -79,17 +78,18 @@ def test_members_leaving_mid_auction_equal_sequential(policy, monkeypatch):
 
 
 def test_batched_trace_tiers_match():
-    e1 = SimEngine(CFG, EBPSM, workload(7), seed=0, batched=False,
-                   trace=True)
+    e1 = SimEngine(CFG, EBPSM, workload(7), seed=0, trace=True)
     e1.run()
-    e2 = SimEngine(CFG, EBPSM, workload(7), seed=0, batched=True, trace=True)
+    e2 = BatchSimEngine(CFG, [(EBPSM, workload(7), 0)], trace=True,
+                        batched=True)
     e2.run()
-    assert e1.trace_rows == e2.trace_rows
+    assert e1.trace_rows == e2.states[0].trace_rows
 
 
 def test_data_index_consistent():
-    eng = SimEngine(CFG, EBPSM, workload(1), seed=0, batched=True)
+    eng = BatchSimEngine(CFG, [(EBPSM, workload(1), 0)], batched=True)
     eng.run()
+    eng = eng.states[0]
     # the inverted index matches per-VM caches for every live VM
     for vm in eng.pool.vms:
         if vm.terminated_ms >= 0:

@@ -69,8 +69,7 @@ def test_grid_members_are_independent():
 
 def test_trace_matches_reference():
     """Placement-level parity: same (time, task, tier, cost, vm) rows."""
-    ref = SimEngine(CFG, EBPSM, workload(4), seed=0, batched=False,
-                    trace=True)
+    ref = SimEngine(CFG, EBPSM, workload(4), seed=0, trace=True)
     ref.run()
     eng = BatchSimEngine(CFG, [(EBPSM, workload(4), 0)], trace=True,
                          batched=True)

@@ -9,8 +9,8 @@ The contract under test (PR 10):
 * streaming aggregates agree with the event log they fold (events seen,
   placements, completions, arrivals) and never perturb results;
 * determinism — ``monitor.json`` and the HTML dashboard are
-  byte-identical across SimEngine vs BatchSimEngine, object vs SoA
-  state layout, repeat runs, and an interrupt/resume cut mid-stream
+  byte-identical across SimEngine vs BatchSimEngine, a member alone or
+  pooled beside another, repeat runs, and an interrupt/resume cut mid-stream
   (the monitor rides the pickled ``elog.sub`` in stream snapshots);
 * alert mechanics — burn-rate algebra, the threshold+MAD rule, and
   fire/clear hysteresis on a synthetic event stream;
@@ -149,18 +149,24 @@ def _report_bytes(m, label="cell"):
 
 
 def test_reports_identical_across_engines_and_layouts():
+    """Repeat runs of one grid engine, a member pooled beside another and
+    the sequential oracle write byte-identical reports."""
     runs = {}
     seq = SimEngine(CFG, EBPSM, workload(7, n=5), seed=0, monitor=True)
     seq.run()
     runs["seq"] = _report_bytes(seq.monitor)
-    for name, soa in (("obj1", False), ("obj2", False), ("soa", True)):
+    for name in ("solo1", "solo2"):
         eng = BatchSimEngine(CFG, [(EBPSM, workload(7, n=5), 0)],
-                             monitor=True, soa=soa)
+                             monitor=True)
         eng.run()
         runs[name] = _report_bytes(eng.states[0].monitor)
-    assert runs["obj1"] == runs["obj2"]        # repeat-run determinism
-    assert runs["obj1"] == runs["soa"]         # layout independence
-    assert runs["obj1"] == runs["seq"]         # sequential-oracle parity
+    eng = BatchSimEngine(CFG, [(EBPSM, workload(3, n=4), 1),
+                               (EBPSM, workload(7, n=5), 0)], monitor=True)
+    eng.run()
+    runs["pooled"] = _report_bytes(eng.states[1].monitor)
+    assert runs["solo1"] == runs["solo2"]      # repeat-run determinism
+    assert runs["solo1"] == runs["pooled"]     # pooled-segment independence
+    assert runs["solo1"] == runs["seq"]        # sequential-oracle parity
 
 
 def test_monitor_pickles_with_event_log():

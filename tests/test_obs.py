@@ -11,7 +11,7 @@ The contract under test (PR 8):
   scheduled tasks, provisions == fleet, event-derived peak == reported
   peak via the shared ``peak_and_mean`` reconstruction);
 * byte-determinism — the same cell + seed exports identical Perfetto
-  JSON and JSONL bytes across repeat runs, SoA vs object state layout,
+  JSON and JSONL bytes across repeat runs, a member alone or pooled,
   and a checkpoint/resume cut mid-stream;
 * the exp harness merge — ``--workers`` events blocks equal serial
   (asserted in ``tests/test_exp.py::test_run_grid_workers_matches_serial``).
@@ -305,14 +305,20 @@ def _trace_bytes(events_log, **kw):
 
 
 def test_export_bytes_identical_across_runs_and_layouts():
+    """Repeat runs of one grid engine and a member pooled beside another
+    export byte-identical traces."""
     runs = {}
-    for name, soa in (("obj1", False), ("obj2", False), ("soa", True)):
+    for name in ("solo1", "solo2"):
         eng = BatchSimEngine(CFG, [(EBPSM, workload(7, n=5), 0)],
-                             events=True, soa=soa)
+                             events=True)
         eng.run()
         runs[name] = _trace_bytes(eng.states[0].elog, label="cell")
-    assert runs["obj1"] == runs["obj2"]        # repeat-run determinism
-    assert runs["obj1"] == runs["soa"]         # layout independence
+    eng = BatchSimEngine(CFG, [(EBPSM, workload(3, n=4), 1),
+                               (EBPSM, workload(7, n=5), 0)], events=True)
+    eng.run()
+    runs["pooled"] = _trace_bytes(eng.states[1].elog, label="cell")
+    assert runs["solo1"] == runs["solo2"]      # repeat-run determinism
+    assert runs["solo1"] == runs["pooled"]     # pooled-segment independence
 
 
 def test_chrome_trace_structure():

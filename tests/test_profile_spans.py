@@ -80,9 +80,9 @@ def _signatures(results):
 
 
 def _counts(prof):
-    """The block's counts: brackets, Algorithm-3 events, kernel rounds."""
+    """The block's counts: brackets and kernel rounds."""
     keys = [*MEMBER_PHASES.values(), *ENGINE_PHASES.values(),
-            "redistribute_events", *KERNEL_COUNTERS]
+            *KERNEL_COUNTERS]
     return {k: prof[k] for k in keys}
 
 

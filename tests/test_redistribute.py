@@ -2,17 +2,16 @@
 
 ``core.budget`` carries two bit-exact implementations of the per-finish
 redistribution (scalar ``update_budget`` reference vs array
-``update_budget_fast`` over a ``RedistState``) plus the opt-in
-round-batched pooled form.  These tests pin:
+``update_budget_fast`` over a ``RedistState``) plus the pooled form
+that absorbs chaos debt.  These tests pin:
 
 * property-style randomized parity (spares and every task budget exactly
   equal, including chained updates and the ``budget_vec`` mirror);
 * the edge cases the sweep regimes are built around — zero surplus, debt
   (negative surplus), a single unscheduled task, everyone topping out,
   and the zero-pool identity skip;
-* engine-level parity: array vs forced-scalar hot path in both
-  redistribute modes, and SimEngine vs BatchSimEngine cross-engine
-  parity in both modes.
+* engine-level parity: array vs forced-scalar hot path, and SimEngine
+  vs BatchSimEngine cross-engine parity for every policy.
 """
 import os
 
@@ -22,7 +21,7 @@ import pytest
 from repro.core import budget as bmod
 from repro.core import cost_tables
 from repro.core.engine import SimEngine, SimState
-from repro.core.jax_engine import simulate_batch
+from repro.core.jax_engine import BatchSimEngine, simulate_batch
 from repro.core.scheduler import ALL_POLICIES
 from repro.core.types import PlatformConfig
 from repro.workflows.dax import generate_workflow
@@ -30,6 +29,7 @@ from repro.workflows.workload import cell_workload
 
 CFG = PlatformConfig()
 EBPSM = next(p for p in ALL_POLICIES if p.name == "EBPSM")
+EBPSM_NC = next(p for p in ALL_POLICIES if p.name == "EBPSM_NC")
 
 
 def _prepared_wf(seed, n=40, app="montage", frac=0.6, rng=None):
@@ -222,51 +222,37 @@ def _key(res):
     return [(w.wid, w.cost, w.finish_ms) for w in res.workflows]
 
 
-def _run_engine(wl, redistribute, scalar, monkeypatch):
+def _run_engine(wl, scalar, monkeypatch, policy=EBPSM, seed=0):
     monkeypatch.setattr(bmod, "_ARRAY_REDIST", not scalar)
     wfs = [w.clone() for w in wl]
-    return SimEngine(CFG, EBPSM, wfs, seed=0,
-                     redistribute=redistribute).run()
+    return SimEngine(CFG, policy, wfs, seed=seed).run()
 
 
-@pytest.mark.parametrize("mode", ["finish", "round"])
-def test_engine_array_vs_scalar_parity(mode, monkeypatch):
+@pytest.mark.parametrize("seed", [0, 7])
+@pytest.mark.parametrize("policy", [EBPSM, EBPSM_NC], ids=lambda p: p.name)
+def test_engine_array_vs_scalar_parity(policy, seed, monkeypatch):
     wl = _workload()
-    r_arr = _run_engine(wl, mode, scalar=False, monkeypatch=monkeypatch)
-    r_sca = _run_engine(wl, mode, scalar=True, monkeypatch=monkeypatch)
+    r_arr = _run_engine(wl, False, monkeypatch, policy, seed)
+    r_sca = _run_engine(wl, True, monkeypatch, policy, seed)
     assert _key(r_arr) == _key(r_sca)
 
 
-@pytest.mark.parametrize("mode", ["finish", "round"])
-def test_cross_engine_parity(mode):
+@pytest.mark.parametrize("policy", ALL_POLICIES, ids=lambda p: p.name)
+def test_cross_engine_parity(policy):
     wl = _workload()
-    seq = SimEngine(CFG, EBPSM, [w.clone() for w in wl], seed=0,
-                    redistribute=mode).run()
-    bat = simulate_batch(CFG, EBPSM, [w.clone() for w in wl], seed=0,
-                         redistribute=mode)
+    seq = SimEngine(CFG, policy, [w.clone() for w in wl], seed=0).run()
+    bat = simulate_batch(CFG, policy, [w.clone() for w in wl], seed=0)
     assert _key(bat.results[0]) == _key(seq)
 
 
-def test_round_mode_coalesces_events():
-    wl = cell_workload(CFG, "cybershake", 8.0, (0.0, 0.25), seed=1,
-                       n_workflows=8, sizes=("medium",))
-
-    def prof(mode):
-        eng = SimEngine(CFG, EBPSM, [w.clone() for w in wl], seed=0,
-                        redistribute=mode, profile=True)
-        eng.run()
-        return eng.profile
-
-    p_fin = prof("finish")
-    assert p_fin["redistribute_events"] == p_fin["redistributions"] > 0
-    p_rnd = prof("round")
-    assert p_rnd["redistribute_events"] == p_fin["redistribute_events"]
-    assert p_rnd["redistributions"] <= p_rnd["redistribute_events"]
-    assert p_rnd["redistributions"] > 0
-
-
 def test_redistribute_mode_validated():
+    """Algorithm 3 runs per task finish: ``BatchSimEngine`` accepts
+    ``redistribute="finish"`` and refuses the retired ``"round"`` and
+    anything else."""
     wl = _workload()[:1]
-    with pytest.raises(ValueError):
-        SimEngine(CFG, EBPSM, [wl[0].clone()], seed=0,
-                  redistribute="never")
+    BatchSimEngine(CFG, [(EBPSM, [wl[0].clone()], 0)],
+                   redistribute="finish")
+    for mode in ("round", "never"):
+        with pytest.raises(ValueError, match="finish"):
+            BatchSimEngine(CFG, [(EBPSM, [wl[0].clone()], 0)],
+                           redistribute=mode)

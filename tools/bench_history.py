@@ -5,9 +5,9 @@
 Scans the given directories (default: ``artifacts/bench`` and
 ``artifacts/exp``) for the benchmark artifacts the suite emits
 (``benchmarks/run.py``, ``repro.exp.run``) and prints one markdown
-trend table: current headline numbers next to the recorded historical
-references baked into each artifact (the PR-3 grid wall, the
-pre-array-path Algorithm-3 share), with the delta.
+trend table: current headline numbers next to the reference each
+artifact records (the sequential oracle's wall, the CI floors), with the
+delta.
 
 Default mode is informational (always exits 0).  ``--check`` turns the
 table into a regression gate: exit 1 when any *dimensionless* metric
@@ -71,30 +71,12 @@ def rows_for(doc: Dict, path: str) -> List[Dict]:
                     "lower_is_better": lower_is_better, "unit": unit,
                     "gate": gate})
 
-    if bench == "grid_wall":
-        row("serial wall", _get(doc, "wall_serial_s"),
-            _get(doc, "pr3_reference", "wall_s"),
-            f"PR3 @{_get(doc, 'pr3_reference', 'commit') or '?'}",
-            lower_is_better=True, unit="s")
-        row("speedup vs PR3", _get(doc, "speedup_vs_pr3_reference"),
-            1.0, "parity", gate=True)
-        row("redistribute share (heavy)",
-            _get(doc, "redistribution", "heavy", "share"),
-            _get(doc, "redistribution", "pre_array_reference", "share"),
-            "pre-array scalar", lower_is_better=True, gate=True)
-    elif bench == "makespan":
+    if bench == "makespan":
         row("batched vs ref speedup", _get(doc, "speedup_batched_vs_ref"),
             1.0, "sequential oracle", gate=True)
         row("batched wall", _get(doc, "batched_wall_s"),
             _get(doc, "ref_wall_s"), "sequential oracle",
             lower_is_better=True, unit="s")
-    elif bench == "stream_scale":
-        row("object/SoA peak RSS ratio",
-            _get(doc, "state_footprint", "object_over_soa_peak_ratio"),
-            1.0, "parity", gate=True)
-        row("object/SoA wall @max members",
-            _get(doc, "wall_object_over_soa_at_max"), 1.0, "parity",
-            gate=True)
     elif bench == "paper_grid":
         row("grid wall", _get(doc, "wall_s"), None, "", unit="s")
         row("EBPSM/MSLBL makespan ratio",
