@@ -24,6 +24,15 @@ other (gated by ``tests/test_redistribute.py``):
   mask compress + table gathers + the bulk SFTD sweep
   (:func:`_bulk_sweep`) instead of a Python sort and per-tier rescan.
 
+The array sweep decides exactly, with no safety margins: the reference's
+``remaining -= delta`` chain is a strict left fold, which
+``np.subtract.accumulate`` computes in the same float64 order, so the
+first paid check it fails is found by one comparison over the chain.
+Where every row starts at tier 0 and each tier above tier 1 costs more
+than the one below, the paid deltas of every pass are fixed by the row
+set, and the whole sweep is one such chain per pass that fails
+(:func:`_chain_sweep`).
+
 Tuning knobs (see the README "Tuning knobs" table):
 
 * ``REPRO_SCALAR_REDIST=1`` — force the scalar :func:`update_budget`
@@ -471,7 +480,8 @@ class RedistState:
     pure function of the rows is memoized between scheduling events —
     the compress itself, the cheapest-column gather and its cumulative
     sum (pass 1 of Algorithm 1 depends on the pool only through two
-    scalars), the ``[U, K]`` tier slice, and a running ``top_sum`` that
+    scalars), the ``[U, K]`` tier slice and its tier steps
+    (:func:`_tier_steps`), and a running ``top_sum`` that
     turns the "everyone tops out" screen into two flops (the cached sum
     drifts from the exact reduction by at most ~n·eps, which the
     screen's margin dominates — it only ever errs toward running the
@@ -487,7 +497,7 @@ class RedistState:
 
     __slots__ = ("order_all", "pos_of", "mask", "budget_vec", "top_sum",
                  "_top_list", "_rows", "_rows_list", "_want", "_cum",
-                 "_want_sum", "_tcr")
+                 "_want_sum", "_tcr", "_steps")
 
     def __init__(self, cfg: PlatformConfig, wf: Workflow,
                  unscheduled: Optional[Sequence[int]] = None,
@@ -537,6 +547,7 @@ class RedistState:
         self._cum = None
         self._want_sum = 0.0
         self._tcr = None
+        self._steps = None
         table = cost_tables.table_for(cfg, wf)
         if table.tiers_monotone:
             self._top_list = table.top_list
@@ -553,6 +564,7 @@ class RedistState:
         self._want = None
         self._cum = None
         self._tcr = None
+        self._steps = None
         if self._top_list is not None:
             self.top_sum -= self._top_list[tid]
 
@@ -565,6 +577,7 @@ class RedistState:
         self._want = None
         self._cum = None
         self._tcr = None
+        self._steps = None
         if self._top_list is not None:
             self.top_sum += self._top_list[tid]
 
@@ -583,6 +596,7 @@ def update_budget_fast(
     finished_tid: int,
     actual_cost: float,
     spare_budget: float,
+    prof: Optional[dict] = None,
 ) -> float:
     """Array-path Algorithm 3 — bit-exact with :func:`update_budget`.
 
@@ -614,7 +628,7 @@ def update_budget_fast(
         return pool
     if pool == 0.0 and not vals.any():
         return 0.0
-    return _distribute_rows(cfg, wf, rs, rows, pool, vals)
+    return _distribute_rows(cfg, wf, rs, rows, pool, vals, prof)
 
 
 def update_budget_pooled(
@@ -623,6 +637,7 @@ def update_budget_pooled(
     rs: RedistState,
     surplus: float,
     spare_budget: float,
+    prof: Optional[dict] = None,
 ) -> float:
     """Pooled Algorithm 3 (array path): one redistribution of
     ``surplus`` with no finishing task — the engines charge a chaos
@@ -643,7 +658,7 @@ def update_budget_pooled(
         return pool
     if pool == 0.0 and not vals.any():
         return 0.0
-    return _distribute_rows(cfg, wf, rs, rows, pool, vals)
+    return _distribute_rows(cfg, wf, rs, rows, pool, vals, prof)
 
 
 def update_budget_pooled_scalar(
@@ -681,6 +696,7 @@ def _distribute_rows(
     rows: np.ndarray,
     budget: float,
     old: Optional[np.ndarray] = None,
+    prof: Optional[dict] = None,
 ) -> float:
     """Algorithm 1 over the rank-ordered row array — the redistribution
     core of the array path, bit-exact with
@@ -770,7 +786,8 @@ def _distribute_rows(
         tcr = rs._tcr
         if tcr is None:
             tcr = rs._tcr = table.tier_cost[rows]
-        remaining = _bulk_sweep(table, tcr, alloc, remaining)
+            rs._steps = _tier_steps(tcr)
+        remaining = _bulk_sweep(tcr, rs._steps, alloc, remaining, prof)
     writeback(alloc)
     return max(remaining, 0.0)
 
@@ -786,153 +803,170 @@ def _discover_tiers(tcr: np.ndarray, alloc: np.ndarray, K: int):
     return tier, np.flatnonzero(tier < K - 1)
 
 
-def _commit_candidates(ci: np.ndarray, cd: np.ndarray, remaining: float):
-    """Sequential paid checks over a sweep's boundary candidates,
-    vectorized where provable.  Returns ``(committed_positions,
-    remaining)`` with ``remaining`` advanced by the exact per-row chain.
+def _tier_steps(tcr: np.ndarray) -> Optional[np.ndarray]:
+    """Rows 1.. of the chain's tier-step matrix, ``[K-2, U]``: row
+    ``k − 1`` is tier ``k+1``'s cost minus tier ``k``'s, row by row — the
+    delta the reference computes for a row that committed at tier ``k``
+    (it sits exactly on ``tcr[u, k]``).  A pure function of the row set
+    (row 0, from tier 0, depends on the pool's pass 1).  None where
+    :func:`_chain_sweep` does not apply: a step that is not positive — a
+    free advance (zero) or a tier cheaper than the one below (a
+    non-monotone row), whose semantics only the loop keeps."""
+    if tcr.shape[1] < 2:
+        return None
+    steps = np.ascontiguousarray((tcr[:, 2:] - tcr[:, 1:-1]).T)
+    return steps if (steps > 0.0).all() else None
 
-    The longest cumulative-sum prefix that provably fits commits in
-    bulk: before prefix candidate ``i`` the reference's remainder is at
-    least ``remaining − Σ_{j≤i} d_j`` up to the chain's accumulated
-    rounding, and the margin (the same shape as the sweep predicates)
-    dominates both that and the cumsum-vs-chain reassociation, so every
-    prefix check passes.  ``remaining`` still advances by the exact
-    subtraction chain.  The tail is then pre-filtered against the
-    post-prefix remainder — the remainder only decreases, so a tail
-    candidate already above it can never commit at its later visit —
-    and the few survivors run the reference's decision loop verbatim.
-    """
-    cum = np.cumsum(cd)
-    margin = 1e-6 + 1e-12 * (abs(remaining) + float(cum[-1])) * ci.size
-    m = int(np.searchsorted(cum, remaining - margin, side="right"))
-    if m:
-        for d in cd[:m].tolist():
-            remaining -= d
-        if m == ci.size:
-            return ci, remaining
-    tail_d = cd[m:]
+
+def _pass_tail(ci: np.ndarray, cd: np.ndarray, f: int, remaining: float):
+    """The rest of a pass after its first failed paid check, at candidate
+    ``f`` (of positive deltas ``cd`` at positions ``ci``): candidates
+    before ``f`` committed, ``f`` failed.  A later candidate already
+    above the remainder can never commit at its visit (the remainder only
+    decreases), so the few survivors run the reference's decision loop
+    verbatim.  Returns ``(committed_positions, remaining)``."""
+    tail_d = cd[f + 1:]
     keep = tail_d <= remaining + 1e-9
-    if not keep.any():
-        return ci[:m], remaining
     commit: List[int] = []
-    for pos, d in zip(ci[m:][keep].tolist(), tail_d[keep].tolist()):
-        if 0 < d <= remaining + 1e-9:
-            remaining -= d
-            commit.append(pos)
-        # else: dead — the remainder shrank past it mid-sweep
+    if keep.any():
+        for pos, d in zip(ci[f + 1:][keep].tolist(), tail_d[keep].tolist()):
+            if d <= remaining + 1e-9:
+                remaining -= d
+                commit.append(pos)
+            # else: dead — the remainder shrank past it mid-pass
     if not commit:
-        return ci[:m], remaining
-    cp = np.asarray(commit, np.int64)
-    if m:
-        cp = np.concatenate([ci[:m], cp])
-    return cp, remaining
+        return ci[:f], remaining
+    return np.concatenate([ci[:f], np.asarray(commit, np.int64)]), remaining
 
 
-def _bulk_sweep(table, tcr: np.ndarray, alloc: np.ndarray,
-                remaining: float) -> float:
-    """SFTD sweep, one whole sweep per step, mutating ``alloc`` in place.
+def _commit_candidates(ci: np.ndarray, cd: np.ndarray, remaining: float):
+    """The sequential paid checks ``d ≤ remaining + 1e-9`` of one pass
+    over its candidates (positive deltas ``cd`` at positions ``ci``, in
+    row order), exactly.  Returns ``(committed_positions, remaining)``.
 
-    The reference sweep visits rows in order, upgrading each by one tier
-    when the paid check ``0 < delta ≤ remaining + 1e-9`` passes, and
-    rescans until a sweep changes nothing.  Two vectorized regimes cover
-    it bit-exactly:
+    ``np.subtract.accumulate`` is a strict left fold in float64, so its
+    chain is the reference's ``remaining -= d`` sequence bit for bit, and
+    the first candidate whose delta passes its chain value (+ 1e-9) is
+    where the reference first stops committing; :func:`_pass_tail` runs
+    the rest of the pass."""
+    chain = np.subtract.accumulate(np.concatenate(([remaining], cd)))
+    bad = cd > chain[:-1] + 1e-9
+    f = int(bad.argmax())
+    if not bad[f]:
+        return ci, float(chain[-1])
+    return _pass_tail(ci, cd, f, float(chain[f]))
 
-    * **Guaranteed success** — the entry remainder exceeds the summed
-      paid deltas by a conservative margin (covering both the
-      pairwise-sum error of the total and the accumulated rounding of
-      the sequential chain), so *every* sequential paid check provably
-      passes: before row ``i`` the reference's remainder is at least
-      ``remaining − Σ_{j<i} d_j`` up to that rounding, which the margin
-      dominates.  Give/tier updates commit as array writes; ``remaining``
-      still advances by the exact per-row subtraction chain (the same
-      float sequence the reference executes), keeping the returned spare
-      bit-identical.
 
-    * **Exhaustion** — otherwise, a paid row whose delta exceeds even
-      the sweep-entry remainder can never succeed (the remainder only
-      decreases and a row's delta is fixed until its tier moves — the
-      same live-list argument as :func:`_distribute_small`): those rows
-      die permanently.  Free advances (``delta ≤ 0``) don't touch the
-      remainder and commit vectorized; the boundary candidates go
-      through :func:`_commit_candidates` (guaranteed prefix + exact
-      tail).
+def _chain_sweep(tcr: np.ndarray, steps: np.ndarray, alloc: np.ndarray,
+                 remaining: float) -> float:
+    """The SFTD sweep as one exact sequential chain, for rows whose tier
+    costs rise from tier 1 up (every step positive) and that all sit at
+    tier 0 (none covers tier 1, so none covers a higher tier either).
 
-    Monotone tier tables (the usual case) take a specialized iteration:
-    after discovery every delta is positive (the highest-covered tier
-    bounds the allocation strictly below the next tier's cost, and a
-    committed row lands exactly on a tier value), so the paid/free
-    bookkeeping collapses — zero deltas (duplicate adjacent tier costs)
-    are detected with one ``all()`` and routed to the generic step.
-    Discovery itself short-circuits when no row covers tier 1 (always
-    true right after pass 1 unless tier costs nearly coincide): every
-    row's highest covered tier is then 0, matching the reference's
-    ``where(any_cov, highest, 0)`` without the ``[n, K]`` scan.
+    While every row of every pass commits, the reference's paid deltas
+    are fixed by the row set: pass ``k`` pays ``tcr[:, k+1] − tcr[:, k]``
+    row by row (``steps[k − 1]``; pass 0 ``tcr[:, 1] − alloc``).  Their
+    ``np.subtract.accumulate``
+    from ``remaining`` is the reference's chain bit for bit, so the first
+    position that fails — a paid check ``d > chain + 1e-9``, or the
+    sweep's ``remaining > 1e-9`` test, which the reference makes only
+    where a pass starts — decides the rest:
 
-    A row that neither advanced nor died keeps its state and is
-    revisited next sweep, exactly like the reference rescan.
+    * a failed pass start: every row stays at that pass's tier;
+    * a failed check at (pass ``p``, row ``i``): rows before ``i`` move
+      up, row ``i`` and every later row the rest of the pass leaves
+      behind never move again (its delta is fixed and the remainder only
+      falls), and :func:`_pass_tail` runs the few survivors.  The rows
+      that moved share one tier, so the next passes are again a chain
+      over their steps — one chain per failed pass, at most ``K − 1``.
+
+    Mutates ``alloc``; returns the remainder.
     """
     K = tcr.shape[1]
-    if K < 2:
-        return remaining
-    mono = table.tiers_monotone
-    if mono and not (alloc >= tcr[:, 1] - 1e-9).any():
-        # No row covers tier 1 ⇒ (monotone) none covers any higher tier
-        # ⇒ every row sits at tier 0 (covered there or not — the
-        # reference assigns 0 either way).
-        tier = np.zeros(alloc.size, np.int64)
-        alive = np.arange(alloc.size)
-    else:
-        tier, alive = _discover_tiers(tcr, alloc, K)
+    alive = np.arange(alloc.size)
+    flat = np.concatenate(([remaining], tcr[:, 1] - alloc, steps.ravel()))
+    t = 0                                   # the alive rows' common tier
+    while True:
+        n = alive.size
+        chain = np.subtract.accumulate(flat)
+        d = flat[1:]
+        bad = d > chain[:-1] + 1e-9
+        bad[::n] |= chain[:-1:n] <= 1e-9    # the test at each pass start
+        f = int(bad.argmax())
+        if not bad[f]:                      # every row reaches the top
+            alloc[alive] = tcr[alive, K - 1]
+            return float(chain[-1])
+        p, i = divmod(f, n)
+        remaining = float(chain[f])
+        tier = t + p
+        if tier:
+            alloc[alive] = tcr[alive, tier]
+        if i == 0 and remaining <= 1e-9:    # the pass never starts
+            return remaining
+        moved, remaining = _pass_tail(alive, d[p * n:(p + 1) * n], i,
+                                      remaining)
+        t = tier + 1
+        if not moved.size:                  # the pass changed nothing
+            return remaining
+        if t == K - 1:
+            alloc[moved] = tcr[moved, t]
+            return remaining
+        alive = moved
+        flat = np.concatenate(([remaining], steps[t - 1:, alive].ravel()))
+
+
+def _bulk_sweep(tcr: np.ndarray, steps: Optional[np.ndarray],
+                alloc: np.ndarray, remaining: float,
+                prof: Optional[dict] = None) -> float:
+    """SFTD sweep, mutating ``alloc`` in place.
+
+    The reference sweep visits rows in order, upgrading each by one tier
+    when the paid check ``0 < delta ≤ remaining + 1e-9`` passes (a free
+    row, ``delta ≤ 0``, just advances), and rescans while ``remaining >
+    1e-9`` and the last pass changed something.  What the input shows
+    picks one of two exact routes:
+
+    * **Chain** (:func:`_chain_sweep`) — every step from tier 1 up is
+      positive (``steps`` not None, :func:`_tier_steps`) and no row
+      covers tier 1 at entry (always so right after pass 1 unless tier
+      costs nearly coincide): every row then sits at tier 0, the
+      reference's ``where(any_cov, highest, 0)`` without the ``[n, K]``
+      scan.
+
+    * **Loop** — anything else: one pass per step over the rows still in
+      play.  A paid row whose delta exceeds even the pass-entry remainder
+      can never succeed (the remainder only decreases and a row's delta
+      is fixed until its tier moves — the same live-list argument as
+      :func:`_distribute_small`) and drops out for good; free rows
+      advance; the candidates commit through :func:`_commit_candidates`.
+      A row that neither advanced nor died is revisited next pass,
+      exactly like the reference rescan.
+
+    ``prof``: the member's profile block, whose ``sweeps_chain`` /
+    ``sweeps_loop`` count the routes taken.
+    """
+    if steps is not None and not (alloc >= tcr[:, 1] - 1e-9).any():
+        if prof is not None:
+            prof["sweeps_chain"] += 1
+        return _chain_sweep(tcr, steps, alloc, remaining)
+    if prof is not None:
+        prof["sweeps_loop"] += 1
+    K = tcr.shape[1]
+    tier, alive = _discover_tiers(tcr, alloc, K)
     while remaining > 1e-9 and alive.size:
         nxt = tcr[alive, tier[alive] + 1]
         delta = nxt - alloc[alive]
-        if mono and delta.all():
-            # Monotone fast step: every row is a paid upgrade.
-            total = float(delta.sum())
-            margin = 1e-6 + 1e-12 * (abs(remaining) + total) * alive.size
-            if remaining > total + margin:
-                alloc[alive] = nxt
-                tier[alive] += 1
-                for d in delta.tolist():     # exact reference chain
-                    remaining -= d
-                alive = alive[tier[alive] < K - 1]
-                continue
-            ci = np.flatnonzero(delta <= remaining + 1e-9)
-            if not ci.size:
-                break                        # everyone died: fixed point
-            cp, remaining = _commit_candidates(ci, delta[ci], remaining)
-            if not cp.size:
-                break
-            rc = alive[cp]
-            alloc[rc] = nxt[cp]
-            tier[rc] += 1
-            alive = rc[tier[rc] < K - 1]
-            continue
-        # Generic step (non-monotone tables, or zero/negative deltas).
         paid = delta > 0.0
-        pd = delta[paid]
-        total = float(pd.sum())
-        margin = 1e-6 + 1e-12 * (abs(remaining) + total) * alive.size
-        if remaining > total + margin:
-            # Guaranteed success: commit the whole sweep in bulk.
-            alloc[alive[paid]] = nxt[paid]
-            tier[alive] += 1                 # free rows advance too
-            for d in pd.tolist():            # exact reference chain
-                remaining -= d
-            alive = alive[tier[alive] < K - 1]
-            continue
         advanced = ~paid                     # free rows always advance
         if advanced.any():
             tier[alive[advanced]] += 1
-        cand = paid & (delta <= remaining + 1e-9)
-        ci = np.flatnonzero(cand)
+        ci = np.flatnonzero(paid & (delta <= remaining + 1e-9))
         if ci.size:
             cp, remaining = _commit_candidates(ci, delta[ci], remaining)
-            if cp.size:
-                rc = alive[cp]
-                alloc[rc] = nxt[cp]
-                tier[rc] += 1
-                advanced[cp] = True
+            rc = alive[cp]
+            alloc[rc] = nxt[cp]
+            tier[rc] += 1
+            advanced[cp] = True
         if not advanced.any():
             break                            # nothing changed: fixed point
         alive = alive[advanced]

@@ -125,8 +125,12 @@ def _phase_block(phases: Dict[str, str]) -> Dict[str, float]:
 
 def new_profile() -> Dict[str, float]:
     """Fresh per-member block: seconds and bracket count of each member
-    phase."""
-    return _phase_block(MEMBER_PHASES)
+    phase, and the Algorithm-3 SFTD sweeps by route
+    (``core.budget._bulk_sweep``: ``sweeps_chain``, ``sweeps_loop``)."""
+    prof = _phase_block(MEMBER_PHASES)
+    prof["sweeps_chain"] = 0
+    prof["sweeps_loop"] = 0
+    return prof
 
 
 def new_engine_profile() -> Dict[str, float]:
@@ -571,7 +575,7 @@ class SimState:
                 if rd is None:
                     rd = st.make_redist(self.cfg)
                 st.spare = budget_mod.update_budget_fast(
-                    self.cfg, wf, rd, tid, actual, st.spare
+                    self.cfg, wf, rd, tid, actual, st.spare, prof
                 )
             else:
                 st.spare = budget_mod.update_budget(
@@ -659,7 +663,7 @@ class SimState:
                 if rd is None:
                     rd = st.make_redist(self.cfg)
                 st.spare = budget_mod.update_budget_pooled(
-                    self.cfg, st.wf, rd, -amount, st.spare
+                    self.cfg, st.wf, rd, -amount, st.spare, prof
                 )
             else:
                 st.spare = budget_mod.update_budget_pooled_scalar(

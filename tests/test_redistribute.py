@@ -10,20 +10,23 @@ that absorbs chaos debt.  These tests pin:
 * the edge cases the sweep regimes are built around — zero surplus, debt
   (negative surplus), a single unscheduled task, everyone topping out,
   and the zero-pool identity skip;
+* the two routes of the SFTD sweep (``core.budget._bulk_sweep``): the
+  exact chain and the loop, each on the inputs that pick it, against the
+  reference ``distribute_budget``;
 * engine-level parity: array vs forced-scalar hot path, and SimEngine
   vs BatchSimEngine cross-engine parity for every policy.
 """
-import os
+import dataclasses
 
 import numpy as np
 import pytest
 
 from repro.core import budget as bmod
 from repro.core import cost_tables
-from repro.core.engine import SimEngine, SimState
+from repro.core.engine import SimEngine, new_profile
 from repro.core.jax_engine import BatchSimEngine, simulate_batch
 from repro.core.scheduler import ALL_POLICIES
-from repro.core.types import PlatformConfig
+from repro.core.types import PlatformConfig, Task, Workflow
 from repro.workflows.dax import generate_workflow
 from repro.workflows.workload import cell_workload
 
@@ -207,6 +210,189 @@ def test_empty_unscheduled_returns_pool():
     assert spare_a == spare_b
     assert spare_a == max(wf.tasks[fin].budget + spare - actual, 0.0) \
         or spare_a >= 0.0
+
+
+# ---------------------------------------------------------------------------
+# the SFTD sweep's two routes (core.budget._bulk_sweep)
+# ---------------------------------------------------------------------------
+
+U_ROWS = 80   # above _PY_DISTRIBUTE_MAX, so both sides sweep with numpy
+
+
+def _synthetic_wf(tcr, cheap):
+    """A workflow of ``len(cheap)`` tasks, ranked by tid, whose cost table
+    holds the given tier costs ``tcr`` ``[U, K]`` and pass-1 column
+    ``cheap``."""
+    tcr = np.ascontiguousarray(tcr, np.float64)
+    cheap = np.ascontiguousarray(cheap, np.float64)
+    wf = Workflow(0, "montage", [Task(i, 1000.0, 1.0, rank=i)
+                                 for i in range(cheap.size)])
+    real = cost_tables.build_table(CFG, wf)
+    est = real.est_full_cost.copy()
+    est[:, 0] = cheap
+    wf.cost_cache = dataclasses.replace(
+        real, est_full_cost=est, tier_cost=tcr, cheap_arr=cheap,
+        top_arr=np.ascontiguousarray(tcr[:, -1]), cheap_list=cheap.tolist(),
+        tier_list=tcr.tolist(), top_list=tcr[:, -1].tolist(),
+        tiers_monotone=bool((np.diff(tcr, axis=1) >= 0).all()))
+    return wf
+
+
+def _rows_vs_reference(wf, budget, rows=None):
+    """``_distribute_rows`` against ``distribute_budget(presorted=True)``
+    over the same rows and pool: the remainder and every budget equal to
+    the bit.  Returns the reference's budgets and the route counters."""
+    ref, arr = wf.clone(), wf.clone()
+    rs = bmod.RedistState(CFG, arr, rows)
+    order = rs.rows()
+    rem_a = bmod.distribute_budget(CFG, ref, budget, task_ids=order.tolist(),
+                                   presorted=True)
+    prof = new_profile()
+    rem_b = bmod._distribute_rows(CFG, arr, rs, order, budget, prof=prof)
+    assert rem_a == rem_b
+    got = [arr.tasks[t].budget for t in order.tolist()]
+    want = [ref.tasks[t].budget for t in order.tolist()]
+    assert got == want
+    assert rs.budget_vec[order].tolist() == want
+    return np.asarray(want), prof
+
+
+def _ladder(k=4):
+    """Dyadic tier costs (every sum below is exact): tier 0 in [1, 2],
+    then steps of 0.5-1.75."""
+    i = np.arange(U_ROWS)
+    steps = [1.0 + (i % 5) * 0.25, 0.5 + (i % 3) * 0.25,
+             0.75 + (i % 2) * 0.5, 1.0 + (i % 4) * 0.25][:k]
+    return np.cumsum(np.stack(steps, axis=1), axis=1)
+
+
+def _chain_deltas(tcr, alloc):
+    """The reference's paid deltas while every row commits, pass by pass."""
+    return np.concatenate([tcr[:, 1] - alloc,
+                           *(tcr[:, k + 1] - tcr[:, k]
+                             for k in range(1, tcr.shape[1] - 1))])
+
+
+def _pool_at_a_delta(off):
+    """The pool runs out at row 37 of pass 1, ``off`` from its delta."""
+    def case():
+        tcr = _ladder()
+        d = _chain_deltas(tcr, tcr[:, 0])
+        m = U_ROWS + 37
+        return tcr, tcr[:, 0], float(d[:m + 1].sum()) + off, "chain"
+    return case
+
+
+def _dip_in_mid_pass():
+    """After row 0 of pass 1 the remainder is 5e-10, under the sweep's
+    1e-9 threshold; rows 1-3 (steps of 3e-10) still commit in that pass,
+    and pass 2 never starts, though rows 0-3's next steps (3e-10) would
+    pass its checks."""
+    tcr = _ladder()
+    tcr[:, 2] = tcr[:, 1] + 0.5
+    tcr[0, 2] = tcr[0, 1] + (0.25 - 5e-10)
+    tcr[1:4, 2] = tcr[1:4, 1] + 3e-10
+    tcr[:, 3] = tcr[:, 2] + 1.0
+    tcr[:4, 3] = tcr[:4, 2] + 3e-10
+    pool = float((tcr[:, 1] - tcr[:, 0]).sum()) + 0.25
+    return tcr, tcr[:, 0], pool, "chain"
+
+
+def _under_tier_zero():
+    """Pass 1 leaves every other row under tier 0's cost (the pass-1
+    column is cheaper than the slowest tier)."""
+    tcr = _ladder()
+    cheap = tcr[:, 0] - np.where(np.arange(U_ROWS) % 2, 0.125, 0.0)
+    d = _chain_deltas(tcr, cheap)
+    return tcr, cheap, float(d[:U_ROWS + 50].sum()) + 0.0625, "chain"
+
+
+def _two_tiers():
+    tcr = _ladder(2)
+    d = _chain_deltas(tcr, tcr[:, 0])
+    return tcr, tcr[:, 0], float(d[:41].sum()) + 0.125, "chain"
+
+
+def _tier_one_covered():
+    tcr = _ladder()
+    cheap = tcr[:, 0].copy()
+    cheap[::10] = tcr[::10, 1]
+    return tcr, cheap, 30.0, "loop"
+
+
+def _zero_step():
+    tcr = _ladder()
+    tcr[::7, 2] = tcr[::7, 1]
+    tcr[:, 3] = tcr[:, 2] + 1.0
+    return tcr, tcr[:, 0], 70.0, "loop"
+
+
+def _non_monotone():
+    tcr = _ladder()
+    tcr[3, 2] = tcr[3, 1] - 0.25
+    return tcr, tcr[:, 0], 70.0, "loop"
+
+
+def _every_row_tops_out():
+    """The pool is exactly what every row needs to reach the top: the
+    "everyone tops out" shortcut (which wants 1e-6 to spare) stays off,
+    and every paid check of every pass passes."""
+    tcr = _ladder()
+    return tcr, tcr[:, 0], float((tcr[:, -1] - tcr[:, 0]).sum()), "chain"
+
+
+CHAIN_CASES = {
+    "pool_at_a_delta_minus_2e-9": _pool_at_a_delta(-2e-9),
+    "pool_at_a_delta_minus_1e-9": _pool_at_a_delta(-1e-9),
+    "pool_at_a_delta_plus_1e-9": _pool_at_a_delta(1e-9),
+    "remainder_under_1e-9_mid_pass": _dip_in_mid_pass,
+    "pass_one_under_tier_zero": _under_tier_zero,
+    "two_tiers": _two_tiers,
+    "tier_one_covered_at_entry": _tier_one_covered,
+    "zero_step": _zero_step,
+    "non_monotone_table": _non_monotone,
+    "every_row_tops_out": _every_row_tops_out,
+}
+
+
+@pytest.mark.parametrize("name", CHAIN_CASES)
+def test_sweep_routes_match_the_reference(name):
+    """Each case takes the route it is built for and matches the
+    reference to the bit."""
+    tcr, cheap, extra, route = CHAIN_CASES[name]()
+    wf = _synthetic_wf(tcr, cheap)
+    want, prof = _rows_vs_reference(wf, float(cheap.sum()) + extra)
+    assert (prof["sweeps_chain"], prof["sweeps_loop"]) == \
+        ((1, 0) if route == "chain" else (0, 1))
+    if name == "every_row_tops_out":
+        assert want.tolist() == tcr[:, -1].tolist()
+    if name == "remainder_under_1e-9_mid_pass":
+        # Rows 0-3 reached tier 2, the rest stayed at tier 1.
+        assert want[:4].tolist() == tcr[:4, 2].tolist()
+        assert want[4:].tolist() == tcr[4:, 1].tolist()
+
+
+def test_sweep_chain_parity_randomized():
+    """Row sets shaped like a grid episode's sweeps (400-700 unscheduled
+    rows of a 1,000-task Montage workflow, K = 4 tiers) at pools across
+    the whole range: the chain takes them, bit-exact with the reference."""
+    rng = np.random.default_rng(2024)
+    wf = generate_workflow("montage", 0, 1000, rng)
+    lo, hi = bmod.min_max_workflow_cost(CFG, wf)
+    bmod.distribute_budget(CFG, wf, lo)
+    assert cost_tables.table_for(CFG, wf).tier_cost.shape[1] == 4
+    chained = 0
+    for _ in range(30):
+        n = int(rng.integers(400, 701))
+        rows = np.sort(rng.choice(wf.n_tasks, n, replace=False)).tolist()
+        table = cost_tables.table_for(CFG, wf)
+        r = np.asarray(rows)
+        need = float(table.cheap_arr[r].sum())
+        top = float(table.top_arr[r].sum())
+        budget = need + float(rng.uniform(0.0, 1.02)) * (top - need)
+        _, prof = _rows_vs_reference(wf, budget, rows)
+        chained += prof["sweeps_chain"]
+    assert chained >= 25
 
 
 # ---------------------------------------------------------------------------
