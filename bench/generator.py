@@ -6,8 +6,14 @@ copies of the program's (``repro.workflows.dax``,
 ``repro.workflows.workload``), kept here so that a change to the program
 cannot move the yardstick.  They build the program's ``Workflow``/``Task``
 types and call its ``min_max_workflow_cost``, which belongs to the
-program's semantics.  They reproduce ``cell_workload`` draw for draw
+program's semantics.  They reproduce ``cell_workload`` and
+``generate_workload`` draw for draw
 (``bench/tests/test_bench_generator.py``).
+
+A configuration's ``workload.app`` names one application, or lists
+several: each workflow of a stream then draws its application from the
+list, in the order given, as ``generate_workload`` draws from
+``WorkloadSpec.apps``.  One name draws exactly as a one-element list.
 
 A cell's streams are fixed by its traffic file: the workflows come from
 its ``workload_seed``, the per-task CPU and bandwidth degradation from its
@@ -25,7 +31,7 @@ program and the reference need, and nothing either of them computed.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Sequence, Tuple
+from typing import List, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -280,20 +286,27 @@ class Stream:
         return sum(len(wf[4]) for wf in self.workload)
 
 
-def paper_stream(cfg: PlatformConfig, app: str, rate: float,
-                 interval: Tuple[float, float], seed: int, n: int,
-                 sizes: Sequence[str]) -> List[Workflow]:
-    """One §5 grid cell's workload (``cell_workload``): a single-app
-    Poisson stream with budgets from one quarter of the cost range."""
+def paper_stream(cfg: PlatformConfig, app: Union[str, Sequence[str]],
+                 rate: float, interval: Tuple[float, float], seed: int,
+                 n: int, sizes: Sequence[str]) -> List[Workflow]:
+    """One §5 grid cell's workload: a Poisson stream with budgets from one
+    quarter of the cost range, each workflow's application drawn from
+    ``app``, one name or several in the order given.  One name gives
+    ``cell_workload``'s stream, a list ``generate_workload``'s with
+    ``apps`` that list."""
+    apps = (app,) if isinstance(app, str) else tuple(app)
+    if not apps or any(a not in APP_GENERATORS for a in apps):
+        raise ValueError(f"workload app {app!r} is not one or more of the "
+                         f"known applications {sorted(APP_GENERATORS)}")
     rng = np.random.default_rng(seed)
     inter_ms = 60.0 * MS / rate
     lo, hi = interval
     t = 0.0
     out: List[Workflow] = []
     for wid in range(n):
-        rng.integers(1)  # the app draw of a one-app stream
+        name = apps[int(rng.integers(len(apps)))]
         size = SIZE_CLASSES[sizes[int(rng.integers(len(sizes)))]]
-        wf = APP_GENERATORS[app](wid, size, rng)
+        wf = APP_GENERATORS[name](wid, size, rng)
         wf.arrival_ms = int(t)
         assign_budgets_uniform(cfg, [wf], rng, lo, hi)
         out.append(wf)
@@ -308,7 +321,8 @@ def streams(cfg: PlatformConfig, workload: dict,
     ``Scenario.workload_cells`` numbers them.  Stream ``d`` of a group
     draws its workflows with the program's per-cell workload seed
     ``7919 (workload_seed + d + 1) + index`` and its degradation from
-    ``degradation_seed + d``."""
+    ``degradation_seed + d``; ``workload["app"]`` goes to
+    :func:`paper_stream` as it stands, one name or a list."""
     n = traffic["streams_per_group"]
     w = traffic["workload_seed"]
     out: List[Stream] = []

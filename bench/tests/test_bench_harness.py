@@ -11,6 +11,7 @@ import statistics
 import subprocess
 import sys
 import time
+from collections import Counter
 from pathlib import Path
 
 import pytest
@@ -64,6 +65,8 @@ LATER_CELLS = [
     ("platform-montage-uncut", "paper-montage-live", "platform",
      {"workflows_per_cell": 100, "budget_intervals": [
          [0.0, 0.25], [0.25, 0.5], [0.5, 0.75], [0.75, 1.0]]}, 4),
+    ("grid-mix", "paper-montage", "grid",
+     {"app": ["cybershake", "epigenome", "ligo", "montage", "sipht"]}, 40),
 ]
 
 
@@ -86,6 +89,42 @@ def test_a_later_cell_composes_from_data(name, config, traffic, change,
     out = run(tiny(cell), 2**31 + 13, 0.01, False,
               t_start=time.perf_counter())
     assert out["correct"] is True and out["attempted"] > 0
+
+
+def test_a_five_application_grid_switches_containers(monkeypatch):
+    """The composed mix, run whole at 4 small and medium workflows per
+    stream: streams mix applications, and an EBPSM member's VMs hold the
+    containers of two of them, as the pool's ``app_image`` index reads at
+    some round."""
+    from bench.run import run
+    from repro.core import jax_engine
+    cell = spec.compose("grid-mix", "paper-montage", "grid",
+                        spec.load_benchmark())
+    cell.conf["workload"].update(app=LATER_CELLS[-1][3]["app"],
+                                 sizes=["small", "medium"])
+    streams = gen.streams(gen.platform_config(cell.conf),
+                          cell.conf["workload"], cell.traffic)
+    assert max(len({wf[1] for wf in s.workload}) for s in streams) >= 2
+    most_images = {}
+
+    class Watched(jax_engine.BatchSimEngine):
+        def run(self, ckpt_hook=None):
+            def hook(engine):
+                for st in engine.states:
+                    held = Counter(v for vids in st.pool.app_image.values()
+                                   for v in vids)
+                    name = st.policy.name
+                    most_images[name] = max([most_images.get(name, 0),
+                                             *held.values()])
+                return ckpt_hook(engine)
+            return super().run(ckpt_hook=hook)
+
+    monkeypatch.setattr(jax_engine, "BatchSimEngine", Watched)
+    out = run(cell, 2**31 + 17, 0.01, False, t_start=time.perf_counter())
+    assert out["correct"] is True
+    assert out["checks"]["mismatches"] == {"value": 0, "limit": 0}
+    assert max(v for k, v in most_images.items()
+               if k.startswith("EBPSM")) >= 2
 
 
 def test_member_order_changes_no_answer_and_no_work():
